@@ -45,7 +45,8 @@ def bench_runtime_setup() -> None:
     ``__main__`` blocks call this, and the engine-creating entry points
     (:func:`run_bench` / :func:`run_batch_bench`) call it defensively —
     DeviceSpec reads ``REPRO_SSD_BW`` at device-creation time, so the env
-    default must precede any ``make_engine``.
+    default must precede any ``make_engine``.  It also turns on JAX's
+    persistent compilation cache (``repro.kernels.ops.enable_compile_cache``).
     """
     global _runtime_ready
     if _runtime_ready:
@@ -57,6 +58,9 @@ def bench_runtime_setup() -> None:
     sys.setswitchinterval(5e-4)
     # benchmark-scaled SSD bandwidth (see repro.core.storage.DeviceSpec.ssd)
     os.environ.setdefault("REPRO_SSD_BW", "30e6")
+    from repro.kernels.ops import enable_compile_cache
+
+    enable_compile_cache()
 
 
 def robust_stats(runs: Sequence[float]) -> Dict[str, float]:

@@ -20,7 +20,7 @@ and the batched vectorized engine across 1–8 devices, reporting the replay
 stage's wall time and records/s for each — the vectorized path must come out
 >= 5x at 100k+ records.  A ``bench=replay_kernel`` row exercises the
 compiled bucket-padded scatter-max apply (``replay_columnar`` with
-``use_kernel=True``; XLA-compiled on CPU, the Pallas kernel on TPU).
+``use_kernel=True``; an XLA scatter program on every backend).
 
 Part 3 (``bench=recover_fused``): end-to-end segmented recovery — the same
 synthesized logs written and sealed onto segment-chained devices, recovered
@@ -284,7 +284,7 @@ def _bench_recover_fused(n_devices: int, n_records: int):
 
 def _bench_replay_kernel(n_devices: int = 2, n_records: int = 4096):
     """Compiled bucket-padded scatter-max apply through ``replay_columnar``
-    (XLA on CPU, the Pallas kernel on TPU — kernels/ops.fused_replay_apply)."""
+    (an XLA scatter program on every backend — kernels/ops.fused_replay_apply)."""
     logs = _synth_logs(n_devices, n_records, n_keys=512)
     cols = [decode_columnar(b) for b in logs]
     rsne = compute_rsne(cols)

@@ -30,10 +30,11 @@ segment) instead of a per-record guarded dict walk.  Three replay modes:
 * ``mode="vectorized"`` (default) — the batched numpy reduction;
 * ``mode="pallas"``     — the *compiled* pipeline: vectorized tile decode
   (`repro.core.fastdecode`, seal-crc verified) feeding the fused hash-slot
-  scatter-max scan (:func:`repro.kernels.ops.fused_replay_scan` — compiled
-  XLA on CPU/GPU, the Pallas kernel on TPU), sealed tiles prefetch-decoded
-  while the previous tile replays; anything out of profile falls back to
-  the batched path with the scatter-max kernel apply;
+  scatter-max scan (:func:`repro.kernels.ops.fused_replay_scan` — an XLA
+  scatter program on every backend, TPU included), sealed tiles
+  prefetch-decoded while the previous tile replays; anything out of profile
+  falls back to the batched path with the compiled scatter-max apply
+  (:func:`repro.kernels.ops.fused_replay_apply`, XLA as well);
 * ``mode="scalar"``     — the original per-record replay, kept as the
   correctness oracle (tested equivalent on randomized logs).
 
@@ -542,7 +543,8 @@ def replay_columnar(
     exactly like the scalar path's strict ``ssn > image.ssn`` guard.
 
     With ``use_kernel=True`` the guarded apply against the image runs through
-    the Pallas SSN scatter-max kernel instead of the numpy reduction.
+    the compiled SSN scatter-max (:func:`repro.kernels.ops.fused_replay_apply`,
+    an XLA scatter) instead of the numpy reduction.
 
     ``record_mask`` (aligned with ``logs``; entries may be None) injects an
     extra per-record commit decision ANDed with the local §5 guard — the
@@ -965,8 +967,9 @@ def recover(
     """Restore a consistent state from checkpoint files + device logs.
 
     ``mode`` selects the replay engine: ``"vectorized"`` (default, batched
-    numpy last-writer-wins), ``"pallas"`` (batched + Pallas scatter-max
-    apply), or ``"scalar"`` (the per-record oracle).  All modes are
+    numpy last-writer-wins), ``"pallas"`` (the compiled fused tile pipeline,
+    else batched + compiled scatter-max apply), or ``"scalar"`` (the
+    per-record oracle).  All modes are
     equivalent; ``parallel`` controls decode threading — the vectorized
     paths decode per (device, sealed segment) pair, so a long-lived
     segmented log fans decode wider than one thread per device — and, for

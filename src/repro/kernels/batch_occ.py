@@ -43,7 +43,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 SEG_MAX_INIT = np.int32(-1)
 NO_WRITER = np.int32(np.iinfo(np.int32).max)
@@ -152,7 +151,7 @@ def seg_reduce(
         in_specs=[item_spec, item_spec],
         out_specs=slot_spec,
         out_shape=jax.ShapeDtypeStruct((1, sp), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -7,12 +7,11 @@ Python-evaluated — so correctness is CI-testable without hardware.
 probe result is cached once per process and ``REPRO_FORCE_INTERPRET=1``
 overrides it so CI can exercise the interpret path deterministically.
 
-The OLTP hot paths don't stop at interpret mode on CPU: the fused entry
-points below (:func:`fused_replay_scan`, :func:`fused_validate_sequence`)
-route to *compiled* XLA twins of the kernel bodies
-(``scatter_max.ssn_scatter_max_xla`` / ``batch_occ.validate_sequence_xla``)
-wherever the Pallas lowering is unavailable, so ``mode="pallas"`` means
-"compiled device path" on every backend.  Their callers pad inputs to the
+The fused entry points below (:func:`fused_replay_scan`,
+:func:`fused_replay_apply`, :func:`fused_validate_sequence`) run *compiled*
+XLA twins of the kernel bodies (``scatter_max.ssn_scatter_max_xla`` /
+``batch_occ.validate_sequence_xla``) on every backend, TPU included: no
+caller passes ``use_pallas=True``.  Their callers pad inputs to the
 power-of-two bucket ladder (``kernels/bucketing.py``), keeping the jit
 cache bounded; :func:`fused_cache_sizes` exposes the per-op compile counts
 that the shape-stability tests and ``benchmarks/fig_kernels.py`` assert on.
@@ -35,6 +34,30 @@ from .rwkv6 import rwkv6_chunked
 from .scatter_max import ssn_scatter_max as _ssn_scatter_max_raw
 from .scatter_max import ssn_scatter_max_xla as _ssn_scatter_max_xla
 from .ssm_scan import ssm_scan_chunked
+
+
+_CHECKOUT = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "..")
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a run and return its
+    directory.  Called by entry points (``chip_smoke.py``, the benchmark
+    harness), never on import.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX.  Otherwise the
+    cache lives at the fixed ``<checkout>/.jax_cache``: the directory is part
+    of the cache key, so a per-run name would never hit.  The minimum compile
+    time drops to 0 because the OLTP kernels compile in well under JAX's 1 s
+    default and would otherwise never be cached.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 @functools.lru_cache(maxsize=1)

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 DEFAULT_CHUNK = 32
 
@@ -131,7 +130,7 @@ def rwkv6_chunked(
             jax.ShapeDtypeStruct((bh, kd, vd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((kd, vd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

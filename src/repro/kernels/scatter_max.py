@@ -44,7 +44,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NO_POS = np.int32(np.iinfo(np.int32).max)
 
@@ -163,7 +162,7 @@ def ssn_scatter_max(
             jax.ShapeDtypeStruct((1, sp), jnp.int32),
             jax.ShapeDtypeStruct((1, sp), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

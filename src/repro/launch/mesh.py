@@ -12,18 +12,14 @@ import jax
 
 
 def _mk(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults to Auto
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available (newer jax); the classic
-    ``with mesh:`` context (same named-axis semantics) otherwise."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    """``jax.set_mesh(mesh)``: the context that makes ``mesh`` current."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

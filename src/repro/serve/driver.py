@@ -37,6 +37,7 @@ class DriverReport:
     rejected: int
     aborted: int
     latencies_ms: np.ndarray = field(default_factory=lambda: np.empty(0))
+    tickets: List[Ticket] = field(default_factory=list)  # submission order
 
     @property
     def goodput_per_s(self) -> float:
@@ -113,6 +114,7 @@ class OpenLoopDriver:
             rejected=n_rej,
             aborted=n_ab,
             latencies_ms=lat,
+            tickets=tickets,
         )
 
 

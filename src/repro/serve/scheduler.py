@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import threading
 import time
+import traceback
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple, Union
@@ -158,6 +159,8 @@ class GroupCommitScheduler:
         self.n_aborted = 0
         self.n_retries = 0
         self.n_exec_errors = 0
+        # traceback of the newest executor fault (None until one happens)
+        self.last_exec_error: Optional[str] = None
         self.n_cuts = 0
         self.n_cut_txns = 0
         self.queue_samples: List[int] = []
@@ -315,14 +318,16 @@ class GroupCommitScheduler:
                 t.spec = t.spec_fn()
                 self._waiting.append(t)
 
-    def _abort_cut(self, cut: List[Ticket]) -> None:
+    def _abort_cut(self, cut: List[Ticket], exc: BaseException) -> None:
         """Backend execution failed outright (engine error, not a validation
         loss): terminate the cut's still-pending tickets explicitly.  An
         admitted transaction must never be stranded in a non-terminal state —
         an explicit ABORTED is the honest outcome when the executor itself
-        fails (lossless-or-explicit, applied to infrastructure faults)."""
+        fails (lossless-or-explicit, applied to infrastructure faults).
+        The fault's traceback is kept for :meth:`stats`."""
         with self._lock:
             self.n_exec_errors += 1
+            self.last_exec_error = "".join(traceback.format_exception(exc))
             for t in cut:
                 if not t.done and t.status != INFLIGHT:
                     t.status = ABORTED
@@ -440,10 +445,10 @@ class GroupCommitScheduler:
             if cut:
                 try:
                     self._execute(cut, time.perf_counter())
-                except Exception:
+                except Exception as e:
                     # the loop must survive an executor fault: strand no
                     # admitted ticket, keep serving the rest of the queue
-                    self._abort_cut(cut)
+                    self._abort_cut(cut, e)
             self.backend.drain()
             with self._lock:
                 released = self._release_acks(time.perf_counter())
@@ -489,6 +494,7 @@ class GroupCommitScheduler:
                 "aborted": self.n_aborted,
                 "retries": self.n_retries,
                 "exec_errors": self.n_exec_errors,
+                "last_exec_error": self.last_exec_error,
                 "cuts": self.n_cuts,
                 "mean_cut": self.n_cut_txns / self.n_cuts if self.n_cuts else 0.0,
                 "queue_depth": len(self._queue),

@@ -19,9 +19,8 @@ SCRIPT = textwrap.dedent("""
     from jax.experimental.shard_map import shard_map
     from repro.parallel.compression import compressed_psum
 
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-          if hasattr(jax.sharding, "AxisType") else {})
-    mesh = jax.make_mesh((4,), ("data",), **kw)
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(0, 1, (4, 512)), jnp.float32)
 

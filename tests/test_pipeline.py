@@ -17,9 +17,8 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.parallel.pipeline import gpipe_apply, sequential_reference
 
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-          if hasattr(jax.sharding, "AxisType") else {})
-    mesh = jax.make_mesh((4,), ("pod",), **kw)
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     S, M, MB, D = 4, 6, 3, 8
     rng = np.random.default_rng(0)
     params = {"w": jnp.asarray(rng.normal(0, 0.5, (S, D, D)), jnp.float32)}

@@ -1,5 +1,6 @@
 """Serving-tier scheduler semantics, deterministic and randomized (stepped
-mode only — no wall clocks anywhere in this file).
+mode, no wall clocks — except the one threaded test of executor faults,
+which exist only in the threaded loop).
 
 Covers: batch-cut triggers (size / latency budget / head-of-line FIFO), the
 ack = durable ∧ committable gate under partial flush interleavings (the
@@ -7,7 +8,8 @@ Qww/Qwr watermark rule observed end-to-end through the scheduler), the RAW
 commit-order invariant under randomized flush schedules asserted against
 Qwr footers in the decoded device logs, lossless-or-explicit admission
 control (including the retry-capacity exemption), max_unacked backpressure,
-the Zipfian generator, and retry-with-backoff under hot-key skew.
+the Zipfian generator, retry-with-backoff under hot-key skew, and an
+executor fault surfacing in ``stats()``.
 """
 
 import random
@@ -355,3 +357,35 @@ def test_sharded_serving_with_cross_shard(tmp_path):
         assert data[k.encode()][0] == b"s-" + k.encode()
     assert data[shard0[5].encode()][0] == b"x0"
     assert data[shard1[5].encode()][0] == b"x1"
+
+
+# --- executor faults (threaded loop) -----------------------------------------
+
+def test_executor_fault_kept_in_stats(tmp_path):
+    """An executor exception aborts its cut explicitly, is counted and kept
+    (traceback) in ``stats()``, and the loop keeps serving afterwards."""
+    be = _backend(tmp_path)
+    real_execute = be.execute
+    calls = []
+
+    def faulty_execute(specs, worker_ids=None, max_rounds=1):
+        calls.append(len(specs))
+        if len(calls) == 1:
+            raise RuntimeError("injected executor fault")
+        return real_execute(specs, worker_ids=worker_ids,
+                            max_rounds=max_rounds)
+
+    be.execute = faulty_execute
+    sched = GroupCommitScheduler(be, ServeConfig(latency_budget_s=1e-4))
+    assert sched.stats()["last_exec_error"] is None
+    sched.start()
+    try:
+        first = sched.submit(_wspec(0))
+        assert first.wait(timeout=10) == ABORTED
+        second = sched.submit(_wspec(1))
+        assert second.wait(timeout=10) == ACKED
+    finally:
+        sched.stop(quiesce=True)
+    st = sched.stats()
+    assert st["exec_errors"] == 1 and st["acked"] == 1
+    assert "RuntimeError: injected executor fault" in st["last_exec_error"]
