@@ -155,7 +155,7 @@ def _run_cell(shards: int, devices: int, batch: int,
     done = 0
     while done < N_TXN:
         if _trace:
-            _td0 = time.perf_counter()
+            _td0 = TRACER.begin(ST_DRIVER)
         specs = wl.next_batch(batch)
         if _trace:
             TRACER.record(ST_DRIVER, t0=_td0, t1=time.perf_counter(),
@@ -163,7 +163,7 @@ def _run_cell(shards: int, devices: int, batch: int,
         res = eng.execute_batch(specs, max_rounds=2)
         done += batch
         if _trace:
-            _td0 = time.perf_counter()
+            _td0 = TRACER.begin(ST_DRIVER)
         pending.extend(res.committed)
         pending.extend(res.cross)
         eng.drain()
@@ -216,7 +216,7 @@ def _overhead_windows(reps: int):
         t0 = time.perf_counter()
         while done < N_TXN:
             if _trace:
-                _td0 = time.perf_counter()
+                _td0 = TRACER.begin(ST_DRIVER)
             specs = wl.next_batch(CAL[0])
             if _trace:
                 TRACER.record(ST_DRIVER, t0=_td0, t1=time.perf_counter(),
@@ -224,7 +224,7 @@ def _overhead_windows(reps: int):
             res = eng.execute_batch(specs, max_rounds=2)
             done += CAL[0]
             if _trace:
-                _td0 = time.perf_counter()
+                _td0 = TRACER.begin(ST_DRIVER)
             pending.extend(res.committed)
             eng.drain()
             keep = []

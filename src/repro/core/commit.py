@@ -97,8 +97,8 @@ class CommitProtocol:
         return ssn <= self.buffers[buffer_id].dsn
 
     def _commit(self, txn: Txn) -> None:
+        txn.t_commit = time.perf_counter()   # stamped before it is seen
         txn.committed = True
-        txn.t_commit = time.perf_counter()
         if self.on_commit is not None:
             self.on_commit(txn)
 

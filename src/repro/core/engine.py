@@ -190,7 +190,7 @@ class PoplarEngine(LoggingEngine):
         """
         _trace = TRACER.enabled
         if _trace:
-            _t0 = time.perf_counter()
+            _t0 = TRACER.begin(ST_PUBLISH)
         if blob:
             self.buffers[buffer_id].fill(offset, seg_idx, blob)
         now = time.perf_counter()
@@ -215,6 +215,8 @@ class PoplarEngine(LoggingEngine):
                 t0=_t0, t1=time.perf_counter(), nbytes=len(blob),
                 n_txn=len(txns),
             )
+        elif _trace:
+            TRACER.end(ST_PUBLISH)
 
     # --- external-coordinator extension points -----------------------------
     # The sharded engine (`repro.shard`) logs cross-shard records through the
@@ -306,7 +308,9 @@ class PoplarEngine(LoggingEngine):
         if _trace or _obs:
             _dsn0 = buf.dsn
             _off0 = buf.flushed_offset
-            _t0 = time.perf_counter()
+            # an idle tick opens no profiler event: the logger polls often
+            _t0 = (TRACER.begin(ST_FLUSH) if _trace and buf.flush_due()
+                   else time.perf_counter())
         n = buf.flush_ready(self.devices[i])
         if _trace and n:
             TRACER.record(
@@ -315,6 +319,8 @@ class PoplarEngine(LoggingEngine):
                 t1=time.perf_counter(), nbytes=buf.flushed_offset - _off0,
                 n_txn=n, aux=n,
             )
+        elif _trace:
+            TRACER.end(ST_FLUSH)
         if _obs:
             names = self._obs_names[i]
             if n:

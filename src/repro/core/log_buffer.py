@@ -203,5 +203,9 @@ class LogBuffer:
                 flushed += 1
         return flushed
 
+    def flush_due(self) -> bool:
+        """A segment is ready for :meth:`flush_ready`."""
+        return self.segindex.flushable() is not None
+
     def pending_bytes(self) -> int:
         return self.offset - self.flushed_offset

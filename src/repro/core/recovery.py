@@ -110,6 +110,13 @@ class RecoveryReport:
     checkpoint_keys: int = 0
     decode_s: float = 0.0
     replay_s: float = 0.0
+    # the fused pipeline's main thread, summed per tile (inside replay_s):
+    # blocked on the next decoded tile (host decode on the critical path,
+    # the tails' decode included), in the compiled scan with its
+    # transfers, and merging the winners into the image
+    fused_wait_s: float = 0.0
+    fused_scan_s: float = 0.0
+    fused_apply_s: float = 0.0
     segments: List[Dict] = field(default_factory=list)
 
     def to_dict(self) -> Dict:
@@ -126,6 +133,9 @@ class RecoveryReport:
             "checkpoint_keys": self.checkpoint_keys,
             "decode_s": self.decode_s,
             "replay_s": self.replay_s,
+            "fused_wait_s": self.fused_wait_s,
+            "fused_scan_s": self.fused_scan_s,
+            "fused_apply_s": self.fused_apply_s,
             "segments": list(self.segments),
         }
 
@@ -832,13 +842,17 @@ def _recover_fused(
     if not all(hasattr(d, "read_segment_entries") for d in devices):
         return False
     per_dev = [d.read_segment_entries() for d in devices]
+    # the split is kept here and reported only once the pipeline served
+    wait = scan = apply = 0.0
 
+    _tw = time.perf_counter()
     tail_tiles: List[FastTile] = []
     for ents in per_dev:
         t = decode_fast_tile(ents[-1][0])
         if t is None:
             return False
         tail_tiles.append(t)
+    wait += time.perf_counter() - _tw
     rsne = None
     for ents, tt, floor in zip(per_dev, tail_tiles, floors):
         if tt.n_records:
@@ -865,22 +879,41 @@ def _recover_fused(
         tiles_iter = ex.map(_decode, sealed)
     else:
         tiles_iter = map(_decode, sealed)
+    tiles = iter(tiles_iter)
     try:
-        for tile, blob_len in tiles_iter:
+        while True:
+            _tw = time.perf_counter()
+            nxt = next(tiles, None)
+            _ts = time.perf_counter()
+            wait += _ts - _tw
+            if nxt is None:
+                break
+            tile, blob_len = nxt
             if tile is None or tile.consumed < blob_len:
                 return False          # out of profile / short sealed blob
             lanes, r, s = _fused_tile_winners(tile, state.rsne)
+            _ta = time.perf_counter()
             _apply_tile_winners(data, tile, lanes)
+            scan += _ta - _ts
+            apply += time.perf_counter() - _ta
             n_rep += r
             n_skip += s
     finally:
         if ex is not None:
             ex.shutdown(wait=False)
     for tt in tail_tiles:
+        _ts = time.perf_counter()
         lanes, r, s = _fused_tile_winners(tt, state.rsne)
+        _ta = time.perf_counter()
         _apply_tile_winners(data, tt, lanes)
+        scan += _ta - _ts
+        apply += time.perf_counter() - _ta
         n_rep += r
         n_skip += s
+    rep = state.report
+    if rep is not None:
+        rep.fused_wait_s, rep.fused_scan_s, rep.fused_apply_s = (
+            wait, scan, apply)
     state.data = data
     state.n_replayed = n_rep
     state.n_skipped_uncommitted = n_skip
@@ -1006,7 +1039,7 @@ def recover(
     floors = device_ssn_floors(devices)
     _trace = TRACER.enabled
     if mode == "scalar":
-        _t0 = time.perf_counter()
+        _t0 = TRACER.begin(ST_RDECODE) if _trace else time.perf_counter()
         device_records = _load_per_device(devices, decode_records, parallel)
         state.rsne = compute_rsne(device_records, floors=floors)
         _t1 = time.perf_counter()
@@ -1017,6 +1050,7 @@ def recover(
                 ST_RDECODE, device=len(devices), t0=_t0, t1=_t1,
                 n_txn=report.n_decoded,
             )
+            TRACER.begin(ST_RREPLAY)   # its row starts at _t1
         _replay_scalar(state, device_records, state.rsne, parallel)
         report.replay_s = time.perf_counter() - _t1
         if _trace:
@@ -1027,7 +1061,7 @@ def recover(
         return _finalize()
 
     if mode == "pallas":
-        _t0 = time.perf_counter()
+        _t0 = TRACER.begin(ST_RREPLAY) if _trace else time.perf_counter()
         if _recover_fused(state, devices, floors, parallel):
             report.fused = True
             # one tiled decode→scan→merge sweep: decode and replay are
@@ -1041,8 +1075,10 @@ def recover(
                     t1=_t0 + report.replay_s, n_txn=state.n_replayed, aux=1,
                 )
             return _finalize()
+        if _trace:
+            TRACER.end(ST_RREPLAY)
 
-    _t0 = time.perf_counter()
+    _t0 = TRACER.begin(ST_RDECODE) if _trace else time.perf_counter()
     logs: List[ColumnarLog] = load_columnar_segmented(
         devices, parallel, segments=report.segments
     )
@@ -1057,6 +1093,7 @@ def recover(
                        if hasattr(d, "durable_bytes")),
             n_txn=report.n_decoded,
         )
+        TRACER.begin(ST_RREPLAY)   # its row starts at _t1
     data, n_replayed, n_skipped = replay_columnar(
         logs, state.rsne, base=state.data or None, use_kernel=(mode == "pallas")
     )

@@ -97,6 +97,11 @@ class Txn:
     cmd_op: Optional[int] = None
     cmd_deps: Optional[List[Tuple[Any, int]]] = None
 
+    # the shard whose engine holds the record, for the tracer's ticket
+    # table: a class default (not a field), set by `repro.shard` on its
+    # executors' transactions while the tracer is enabled
+    trace_shard = 0
+
     # lifecycle timestamps (perf accounting)
     t_start: float = 0.0
     t_precommit: float = 0.0  # SSN allocated + record buffered ("pre-committed")

@@ -500,7 +500,7 @@ class BatchOCC:
         committed once the engine drains them) and the never-won indices."""
         if len(specs) == 0:
             return BatchResult()
-        t_ent = time.perf_counter() if TRACER.enabled else None
+        t_ent = TRACER.begin(ST_VALIDATE) if TRACER.enabled else None
         return self._run(_Flat.from_specs(self.table, specs, self.policy),
                          worker_ids, max_rounds, t_enter=t_ent)
 
@@ -526,7 +526,7 @@ class BatchOCC:
         materialized); everything else matches :meth:`execute_batch`."""
         if len(rd_start) <= 1:
             return BatchResult()
-        t_ent = time.perf_counter() if TRACER.enabled else None
+        t_ent = TRACER.begin(ST_VALIDATE) if TRACER.enabled else None
         flat = _Flat.from_indexed(self.table, rd_row, rd_start, wr_row,
                                   wr_start, wr_vals, observed, wr_vlen)
         return self._run(flat, worker_ids, max_rounds, t_enter=t_ent)
@@ -559,7 +559,8 @@ class BatchOCC:
                 TRACER.ctx.shard = self.trace_shard
                 # first round: the span starts at entry so the spec
                 # flattening cost is attributed to validate, not lost
-                _tv0 = t_enter if t_enter is not None else time.perf_counter()
+                _tv0 = (t_enter if t_enter is not None
+                        else TRACER.begin(ST_VALIDATE))
                 t_enter = None
             with table.mutex:
                 # --- gather the round's access view -------------------------
@@ -598,13 +599,17 @@ class BatchOCC:
                     REGISTRY.count("occ.validate.losses",
                                    len(active) - len(win_local))
                 if _trace:
-                    _tv1 = time.perf_counter()
+                    # validate ends where the sequence span (and its
+                    # profiler event) begins
+                    _tv1 = TRACER.begin(ST_SEQUENCE)
                     TRACER.record(
                         ST_VALIDATE, shard=self.trace_shard, batch=_bid,
                         t0=_tv0, t1=_tv1, n_txn=len(active),
                         aux=len(win_local),
                     )
                 if not len(win_local):
+                    if _trace:
+                        TRACER.end(ST_SEQUENCE)
                     break  # nothing can make progress without external change
                 win = active[win_local]
 
@@ -686,7 +691,7 @@ class BatchOCC:
                     )
                 for buf_id in write_bufs:
                     if _trace:
-                        _te0 = time.perf_counter()
+                        _te0 = TRACER.begin(ST_ENCODE)
                     sel = np.flatnonzero(has_writes & (bufs == buf_id))
                     b_ssns, b_offs, seg = self.engine.buffers[buf_id].reserve_batch(
                         bases[sel], flat.rec_len[win[sel]]
@@ -751,7 +756,7 @@ class BatchOCC:
                 # last-write-wins, like the scalar apply loop); the finally
                 # guarantees the locks can't wedge the rows
                 if _trace:
-                    _tw0 = time.perf_counter()
+                    _tw0 = TRACER.begin(ST_WRITEBACK)
                 tids = np.fromiter((t.tid for t in txns), np.int64, len(txns))
                 table.claim_rows(rows, np.repeat(tids, flat.wr_len[win]))
                 try:

@@ -141,11 +141,13 @@ class ReplicaApplier:
         self.n_rounds += 1
         _trace = TRACER.enabled
         if _trace:
-            _t0 = time.perf_counter()
+            _t0 = TRACER.begin(ST_APPLY)
         for log in new_logs:
             if log is not None and log.n_records:
                 self.pending.append(_Chunk(log))
         if not self.pending:
+            if _trace:
+                TRACER.end(ST_APPLY)
             return 0
 
         # per-chunk decision mask: §5 guard & not-yet-applied & gate
@@ -193,6 +195,8 @@ class ReplicaApplier:
                 ST_APPLY, shard=self.trace_shard, t0=_t0,
                 t1=time.perf_counter(), n_txn=newly, aux=watermark,
             )
+        elif _trace:
+            TRACER.end(ST_APPLY)
         return newly
 
     def _table_lookup(self, key: bytes):

@@ -97,15 +97,16 @@ class LogShipper:
         self.n_polls += 1
         _trace = TRACER.enabled
         if _trace:
-            _t0 = time.perf_counter()
+            _t0 = TRACER.begin(ST_SHIP)
         new = self.source.read_from(self.consumed + len(self._tail))
         buf = self._tail + new if self._tail else new
-        if not buf:
-            return None
-        log, used = decode_columnar_stream(buf)
-        self._tail = buf[used:]
-        self.consumed += used
-        if log.n_records == 0:
+        if buf:
+            log, used = decode_columnar_stream(buf)
+            self._tail = buf[used:]
+            self.consumed += used
+        if not buf or log.n_records == 0:
+            if _trace:
+                TRACER.end(ST_SHIP)
             return None
         self.frontier = max(self.frontier, log.last_ssn)
         self.n_shipped += log.n_records

@@ -58,9 +58,11 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple, Union
 
+import numpy as np
+
 from ..db.batch import TxnSpec
 from ..obs.metrics import REGISTRY
-from ..trace.span import ST_ACK, ST_CUT, TRACER
+from ..trace.span import ST_ACK, ST_CUT, TICKET_DTYPE, TRACER
 
 # ticket lifecycle ----------------------------------------------------------
 QUEUED = "queued"          # admitted, waiting for a batch cut
@@ -107,6 +109,12 @@ class Ticket:
     # timestamps: steps in stepped mode, perf_counter seconds in threaded
     t_submit: float = 0.0
     t_ack: float = 0.0
+    # perf_counter seconds in both modes, stamped only while the tracer is
+    # enabled (its ticket table): the client's call to submit (admission,
+    # a regenerated spec's first build included), and the end of the cut
+    # that ran the current attempt
+    t_submit_s: float = 0.0
+    t_cut: float = 0.0
     _backoff_until: float = 0.0
     _event: Optional[threading.Event] = None
 
@@ -188,8 +196,9 @@ class GroupCommitScheduler:
         assert (spec is None) != (make_spec is None), (
             "pass exactly one of spec / make_spec"
         )
+        t_call = time.perf_counter() if TRACER.enabled else 0.0
         fn = make_spec if make_spec is not None else (lambda: spec)
-        t = Ticket(client_id=client_id, spec_fn=fn)
+        t = Ticket(client_id=client_id, spec_fn=fn, t_submit_s=t_call)
         if self._threaded:
             t._event = threading.Event()
         with self._lock:
@@ -252,7 +261,7 @@ class GroupCommitScheduler:
         the log bytes independent of where cuts land."""
         _trace = TRACER.enabled
         if _trace:
-            _t0 = time.perf_counter()
+            _t0 = TRACER.begin(ST_CUT)
         cut: List[Ticket] = []
         claimed: set = set()
         while self._queue and len(cut) < self.cfg.max_batch:
@@ -265,10 +274,14 @@ class GroupCommitScheduler:
             self._n_admitted_queue -= 1
             cut.append(t)
         if _trace and cut:
+            _t1 = time.perf_counter()
+            for t in cut:
+                t.t_cut = _t1
             TRACER.record(
-                ST_CUT, t0=_t0, t1=time.perf_counter(),
-                n_txn=len(cut), aux=len(self._queue),
+                ST_CUT, t0=_t0, t1=_t1, n_txn=len(cut), aux=len(self._queue),
             )
+        elif _trace:
+            TRACER.end(ST_CUT)
         if REGISTRY.enabled:
             REGISTRY.gauge_set("serve.queue_depth", float(len(self._queue)))
             REGISTRY.count("serve.cut_txns", len(cut))
@@ -347,15 +360,23 @@ class GroupCommitScheduler:
     def _release_acks(self, now: float) -> int:
         """Release every in-flight transaction whose backend drain marked it
         durably committed, in SSN order (within one release round a RAW
-        dependency always acks before its dependent — SSNs order them)."""
-        _trace = TRACER.enabled
-        if _trace:
-            _t0 = time.perf_counter()
-        ready = [t for t in self._inflight if t.txn.committed]
+        dependency always acks before its dependent — SSNs order them).
+        With the tracer enabled the round is an ``ack`` span, and each
+        released ticket a row of its ticket table (``t_ack``: the span's
+        start, after every released commit was seen)."""
+        # one pass: a drain on a logger thread may commit a transaction
+        # at any moment, and it must land in exactly one of the two lists
+        ready: List[Ticket] = []
+        rest: List[Ticket] = []
+        for t in self._inflight:
+            (ready if t.txn.committed else rest).append(t)
         if not ready:
             return 0
+        _trace = TRACER.enabled
+        if _trace:
+            _t0 = TRACER.begin(ST_ACK)
         ready.sort(key=lambda t: t.ssn)
-        self._inflight = [t for t in self._inflight if not t.txn.committed]
+        self._inflight = rest
         for t in ready:
             t.status = ACKED
             t.t_ack = now
@@ -369,12 +390,29 @@ class GroupCommitScheduler:
                 ST_ACK, txn_lo=ready[0].ssn, txn_hi=ready[-1].ssn,
                 t0=_t0, t1=time.perf_counter(), n_txn=len(ready),
             )
+            self._record_tickets(ready, _t0)
         if REGISTRY.enabled:
             REGISTRY.count("serve.acked", len(ready))
             # units follow the scheduler clock: steps (stepped) or seconds
             REGISTRY.observe_many("serve.ack_latency",
                                   [t.latency() for t in ready])
         return len(ready)
+
+    @staticmethod
+    def _record_tickets(ready: List[Ticket], t_ack: float) -> None:
+        """One ticket-table row per released ticket, gathered column by
+        column (no per-ticket objects) and written in one call.  A
+        cross-shard ``XTxn`` has no single buffer or shard: -1 for both."""
+        rows = np.empty(len(ready), TICKET_DTYPE)
+        rows["ssn"] = [t.ssn for t in ready]
+        rows["shard"] = [getattr(t.txn, "trace_shard", -1) for t in ready]
+        rows["device"] = [getattr(t.txn, "buffer_id", -1) for t in ready]
+        rows["t_submit"] = [t.t_submit_s for t in ready]
+        rows["t_cut"] = [t.t_cut for t in ready]
+        rows["t_precommit"] = [t.txn.t_precommit for t in ready]
+        rows["t_commit"] = [t.txn.t_commit for t in ready]
+        rows["t_ack"] = t_ack
+        TRACER.record_many(rows)
 
     # --- stepped mode -------------------------------------------------------
     def step(self, tick_parts: Optional[Sequence[int]] = None) -> int:

@@ -109,7 +109,8 @@ class CrossShardCoordinator:
         a validation abort."""
         router = self.router
         shard_ids = sorted(shard_ids) if shard_ids else router.shards_of(spec)
-        t_start = time.perf_counter()
+        t_start = (TRACER.begin(ST_XPREPARE) if TRACER.enabled
+                   else time.perf_counter())
 
         # group accesses per shard (observed SSNs stay aligned with reads)
         rd_keys: Dict[int, List[str]] = {p: [] for p in shard_ids}
@@ -144,6 +145,8 @@ class CrossShardCoordinator:
                     self.aborts += 1
                     if REGISTRY.enabled:
                         REGISTRY.count("shard.xprepare.aborts")
+                    if TRACER.enabled:
+                        TRACER.end(ST_XPREPARE)
                     return None
                 obs = np.asarray(rd_obs[p], dtype=np.int64)
                 if len(obs) and (
@@ -152,6 +155,8 @@ class CrossShardCoordinator:
                     self.aborts += 1
                     if REGISTRY.enabled:
                         REGISTRY.count("shard.xprepare.aborts")
+                    if TRACER.enabled:
+                        TRACER.end(ST_XPREPARE)
                     return None
 
             # --- sequence: shared base, one record per participant -------
@@ -236,8 +241,8 @@ class CrossShardCoordinator:
                 sh.table.release_rows(part.wr_rows)
             with sh.engine._count_lock:
                 sh.engine.txn_committed += 1
+        xt.t_commit = time.perf_counter()   # stamped before it is seen
         xt.committed = True
-        xt.t_commit = time.perf_counter()
 
     def sweep(self) -> int:
         """Commit every pending cross-shard transaction whose records are

@@ -34,6 +34,7 @@ from ..core.txn import Txn
 from ..db.array_table import ArrayTable
 from ..db.batch import BatchOCC, TxnSpec
 from ..db.occ import TID_STRIDE, TidStripe
+from ..trace.span import TRACER
 from .coordinator import CrossShardCoordinator, XTxn
 from .router import Router
 
@@ -159,6 +160,9 @@ class ShardedEngine:
             idxs = [i for i, _ in per_shard[p]]
             sub = [s for _, s in per_shard[p]]
             r = self.shards[p].occ.execute_batch(sub, max_rounds=max_rounds)
+            if TRACER.enabled:
+                for t in r.committed:
+                    t.trace_shard = p
             res.committed.extend(r.committed)
             res.committed_idx.extend(idxs[j] for j in r.committed_idx)
             res.aborted.extend(idxs[j] for j in r.aborted)

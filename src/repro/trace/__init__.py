@@ -12,6 +12,7 @@ simulator to pick batch size and device count per workload
 
 from .span import (  # noqa: F401
     CPU_STAGES,
+    EVENT_NAMES,
     STAGE_NAMES,
     ST_ACK,
     ST_APPLY,
@@ -19,6 +20,7 @@ from .span import (  # noqa: F401
     ST_DRIVER,
     ST_ENCODE,
     ST_FLUSH,
+    ST_GC,
     ST_PUBLISH,
     ST_RDECODE,
     ST_RREPLAY,
@@ -27,7 +29,10 @@ from .span import (  # noqa: F401
     ST_VALIDATE,
     ST_WRITEBACK,
     ST_XPREPARE,
+    TICKET_COLUMNS,
+    TICKET_DTYPE,
     TRACER,
+    TicketDump,
     TraceDump,
     Tracer,
     disable,

@@ -170,7 +170,9 @@ def _chain(preds: List[List[int]], idxs: Sequence[int]) -> None:
 
 def build_dag(dump: TraceDump) -> TraceDAG:
     """Build the dependency DAG from a trace dump (see module docstring for
-    the edge semantics)."""
+    the edge semantics; ``gc`` rows are left out: a pause is no node of
+    the pipeline)."""
+    dump = dump.without_gc()
     n = dump.n
     preds: List[List[int]] = [[] for _ in range(n)]
     st = dump.stage

@@ -159,3 +159,39 @@ def test_checkpoint_elr_validation_times_out():
                           csn_fn=lambda: 0)
     with pytest.raises(TimeoutError):
         ck.run_once([[(b"k", b"v", 99)]], validate_timeout=0.05)
+
+
+def _sealed_devices(n_devices=2, n_records=3000, per_segment=1000):
+    """Devices holding sealed segments of 1,000 one-write records each and
+    a tail, enough lanes for the fused scan's compiled path."""
+    from repro.core.storage import DeviceSpec, StorageDevice
+
+    devs = []
+    for d in range(n_devices):
+        dev = StorageDevice(DeviceSpec.null(), clock="virtual")
+        for lo in range(0, n_records, per_segment):
+            txns = []
+            for i in range(lo, min(lo + per_segment, n_records)):
+                t = Txn(tid=i, write_set=[(f"k{i % 700}", b"v%d" % i)])
+                t.ssn = d * n_records + i + 1
+                txns.append(t)
+            dev.write(b"".join(t.encode() for t in txns))
+            if lo + per_segment < n_records:
+                dev.seal(txns[-1].ssn)
+        devs.append(dev)
+    return devs
+
+
+@pytest.mark.parametrize("parallel", [False, True])
+def test_fused_recovery_splits_its_replay_time(parallel):
+    devs = _sealed_devices()
+    st = recover(devs, mode="pallas", parallel=parallel)
+    rep = st.report
+    assert rep.fused
+    split = (rep.fused_wait_s, rep.fused_scan_s, rep.fused_apply_s)
+    assert all(x >= 0 for x in split) and rep.fused_scan_s > 0
+    assert sum(split) <= rep.replay_s
+    assert st.data == recover(devs, mode="scalar").data
+    other = recover(devs, mode="vectorized").report
+    assert (other.fused_wait_s, other.fused_scan_s,
+            other.fused_apply_s) == (0.0, 0.0, 0.0)
