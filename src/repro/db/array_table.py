@@ -7,7 +7,9 @@ state as struct-of-arrays over dense integer rows:
 * ``ssn``        — int64 per-tuple sequence numbers (Algorithm 1 state);
 * ``lock_owner`` — int64 write-lock owner tids (0 = free), maintained
   vectorized so batch validation can test/claim whole index arrays;
-* ``values``     — object array of value bytes.
+* ``key_len``    — int64 length of each row's encoded key;
+* ``key_bytes``  — object ndarray of each row's exact key bytes (framing);
+* ``values``     — object ndarray of value bytes.
 
 A ``key -> row`` dict maps the flat key space onto rows; rows are append
 -only and never reused, so an index array gathered once stays valid for the
@@ -15,6 +17,11 @@ life of the table.  This is the substrate of the batched OCC executor
 (`repro.db.batch`): validation, SSN base computation, and write-back are
 all gathers/scatters over these columns — the per-tuple lock round-trips of
 the scalar path collapse into a handful of array ops under one mutex.
+
+No attribute is a collector-tracked container whose length grows with the
+rows: CPython's cyclic collector does not track an ndarray, and the
+str -> int index holds no tracked object, so a full collection does not
+walk the table (at 10M rows two per-row lists made most of each pause).
 
 The layout deliberately mirrors the columnar *log* layout
 (:class:`~repro.core.txn.ColumnarLog`) that recovery decodes: the same
@@ -39,11 +46,11 @@ class ArrayTable:
         self.name = name
         capacity = max(capacity, 1)
         self._index: Dict[str, int] = {}
-        self._keys: List[str] = []
-        self._keys_b: List[bytes] = []   # encoded key bytes (log framing)
+        self._n = 0
         self.ssn = np.zeros(capacity, dtype=np.int64)
         self.lock_owner = np.zeros(capacity, dtype=np.int64)
         self.key_len = np.zeros(capacity, dtype=np.int64)  # len(encoded key)
+        self.key_bytes = np.empty(capacity, dtype=object)
         self.values = np.empty(capacity, dtype=object)
         # one mutex guards structural growth and the vectorized
         # claim/apply/release critical sections of the batch executor
@@ -52,10 +59,10 @@ class ArrayTable:
     # --- rows ----------------------------------------------------------------
     @property
     def n(self) -> int:
-        return len(self._keys)
+        return self._n
 
     def __len__(self) -> int:
-        return len(self._keys)
+        return self._n
 
     def __contains__(self, key: str) -> bool:
         return key in self._index
@@ -65,7 +72,7 @@ class ArrayTable:
         if need <= cap:
             return
         new_cap = max(need, cap * 2)
-        for name in ("ssn", "lock_owner", "key_len", "values"):
+        for name in ("ssn", "lock_owner", "key_len", "key_bytes", "values"):
             old = getattr(self, name)
             arr = np.zeros(new_cap, old.dtype) if old.dtype != object else np.empty(new_cap, object)
             arr[:cap] = old
@@ -82,12 +89,12 @@ class ArrayTable:
             return row
 
     def _insert_locked(self, key: str, kb: Optional[bytes] = None) -> int:
-        row = len(self._keys)
+        row = self._n
         self._grow(row + 1)
         self._index[key] = row
-        self._keys.append(key)
+        self._n = row + 1
         kb = key.encode() if kb is None else kb
-        self._keys_b.append(kb)
+        self.key_bytes[row] = kb
         self.key_len[row] = len(kb)
         self.values[row] = b""
         return row
@@ -174,13 +181,15 @@ class ArrayTable:
         return self._index.get(key)
 
     def key_of(self, row: int) -> str:
-        return self._keys[row]
+        """The index's string for ``row``: the utf-8/surrogateescape
+        decoding of its key bytes (see :meth:`rows_for_bytes`)."""
+        return self.key_bytes[row].decode("utf-8", "surrogateescape")
 
     def key_bytes_for(self, rows: Sequence[int]) -> List[bytes]:
-        """Encoded key bytes for ``rows`` (log-record framing: the indexed
-        batch pipeline encodes keys straight from this column)."""
-        kb = self._keys_b
-        return [kb[r] for r in rows]
+        """Encoded key bytes for ``rows``, an int sequence or index array
+        (log-record framing: the indexed batch pipeline encodes keys
+        straight from this column)."""
+        return self.key_bytes[np.asarray(rows, dtype=np.int64)].tolist()
 
     # --- point access (tests / drivers) -------------------------------------
     def get(self, key: str) -> Optional[Tuple[bytes, int]]:
@@ -234,6 +243,6 @@ class ArrayTable:
         """``key_bytes -> (value, ssn)`` — the :class:`RecoveredState.data`
         shape, for direct comparison against a post-crash recovery."""
         return {
-            self._keys_b[row]: (self.values[row], int(self.ssn[row]))
+            self.key_bytes[row]: (self.values[row], int(self.ssn[row]))
             for row in self._index.values()
         }

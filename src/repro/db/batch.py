@@ -717,7 +717,7 @@ class BatchOCC:
                             np.where(flat.rd_len[gw] > 0, FLAG_HAS_READS, 0
                                      ).astype(np.uint8),
                             flat.wr_len[gw],
-                            table.key_bytes_for(g_rows.tolist()),
+                            table.key_bytes_for(g_rows),
                             flat.wr_vals[g_idx],
                             klen=table.key_len[g_rows],
                             vlen=flat.wr_vlen[g_idx],
