@@ -115,6 +115,12 @@ class SingleBackend:
             aborted=list(r.aborted),
         )
 
+    @staticmethod
+    def cut_claims(spec: TxnSpec) -> List[str]:
+        """Keys no later transaction of the same cut may read or write: the
+        keys ``spec`` writes (one batch runs in cut order, first-come-wins)."""
+        return [k for k, _ in spec.writes]
+
     def tick(self, parts: Optional[Sequence[int]] = None) -> None:
         """Stepped flush: force one logger tick on the given buffers (all by
         default).  ``parts`` indexes buffers — partial ticks let tests hold
@@ -187,6 +193,15 @@ class ShardedBackend:
         )
         committed.extend(zip(r.cross_idx, r.cross))
         return ExecOutcome(committed=committed, aborted=list(r.aborted))
+
+    def cut_claims(self, spec: TxnSpec) -> List[str]:
+        """Keys no later transaction of the same cut may read or write: the
+        keys ``spec`` writes, and every key of a cross-shard ``spec``,
+        whose prepare runs after the cut's single-shard sub-batches (a
+        later single-shard writer of a key it reads would run first)."""
+        if len(self.eng.router.shards_of(spec)) > 1:
+            return list(spec.reads) + [k for k, _ in spec.writes]
+        return [k for k, _ in spec.writes]
 
     def tick(self, parts: Optional[Sequence[int]] = None) -> None:
         """Stepped flush; ``parts`` indexes shards (every buffer of each)."""

@@ -15,18 +15,22 @@ which applies the same ``committable()`` predicate per participant) can
 set.  Ack = durable ∧ committable, end to end.
 
 Batch cutting is **strict-FIFO and conflict-free**: a cut is the longest
-queue prefix in which no two transactions touch a common key, stopped at
-the first conflicting transaction (head-of-line) or at ``max_batch``.
-Two consequences:
+queue prefix in which no transaction touches (reads or writes) a key that
+an earlier transaction of the cut writes, stopped at the first transaction
+that does (head-of-line) or at ``max_batch``.  Reads of one key share a
+cut, and so does a write after a read of its key: that pair has no RAW or
+WAW dependency, and first-come-wins lets both win.  Two consequences:
 
 * within a cut every transaction wins validation round 1 (no intra-batch
   first-come-wins losses), so a group-commit round never silently reorders
-  admitted work — commit order *is* admission order, per key and globally;
-* the device logs are therefore *invariant under cut points*: for a
-  conflict-free arrival schedule, any cut sequence produces byte-identical
-  logs to one direct ``execute_batch`` of the same transactions, and for
-  arbitrary schedules any two cut configurations produce byte-identical
-  logs to each other.  The property tests pin both.
+  admitted work — the per-key order of *conflicting* transactions (a RAW
+  or WAW pair) is admission order;
+* the device logs are therefore *invariant under cut points*: a read-only
+  winner reserves no slot, and a later writer's SSN does not depend on it,
+  so for a conflict-free arrival schedule any cut sequence produces
+  byte-identical logs to one direct ``execute_batch`` of the same
+  transactions, and for arbitrary schedules any two cut configurations
+  produce byte-identical logs to each other.  The property tests pin both.
 
 Two operating modes, mirroring the engine:
 
@@ -133,10 +137,6 @@ class Ticket:
         return self.t_ack - self.t_submit
 
 
-def _keys_of(spec: TxnSpec) -> List[str]:
-    return list(spec.reads) + [k for k, _ in spec.writes]
-
-
 class GroupCommitScheduler:
     """Coalesces single-transaction submissions into group-commit batches.
 
@@ -171,6 +171,11 @@ class GroupCommitScheduler:
         self.last_exec_error: Optional[str] = None
         self.n_cuts = 0
         self.n_cut_txns = 0
+        # why each cut ended: a conflicting ticket, max_batch, empty queue
+        self.n_cut_stop_conflict = 0
+        self.n_cut_stop_full = 0
+        self.n_cut_stop_drained = 0
+        self.n_acked_read_only = 0
         self.queue_samples: List[int] = []
         self._max_queue = 0
         self._max_unacked_seen = 0
@@ -256,20 +261,33 @@ class GroupCommitScheduler:
 
     def _cut(self) -> List[Ticket]:
         """Longest conflict-free FIFO prefix of the queue, ≤ max_batch.
-        Stops at the first transaction sharing any key with the cut so far —
-        per-key *and* global commit order equal admission order, which makes
-        the log bytes independent of where cuts land."""
+        Stops at the first transaction that reads or writes a key an
+        earlier transaction of the cut writes (the backend's
+        ``cut_claims``) — per-key order of conflicting transactions equals
+        admission order, which makes the log bytes independent of where
+        cuts land.  Counts why the cut ended."""
         _trace = TRACER.enabled
         if _trace:
             _t0 = TRACER.begin(ST_CUT)
         cut: List[Ticket] = []
-        claimed: set = set()
-        while self._queue and len(cut) < self.cfg.max_batch:
-            t = self._queue[0]
-            keys = _keys_of(t.spec)
-            if any(k in claimed for k in keys):
+        written: set = set()
+        claims = self.backend.cut_claims
+        max_batch = self.cfg.max_batch
+        while True:
+            if len(cut) >= max_batch:
+                self.n_cut_stop_full += 1
                 break
-            claimed.update(keys)
+            if not self._queue:
+                self.n_cut_stop_drained += 1
+                break
+            t = self._queue[0]
+            spec = t.spec
+            if any(k in written for k in spec.reads) or any(
+                k in written for k, _ in spec.writes
+            ):
+                self.n_cut_stop_conflict += 1
+                break
+            written.update(claims(spec))
             self._queue.popleft()
             self._n_admitted_queue -= 1
             cut.append(t)
@@ -296,7 +314,8 @@ class GroupCommitScheduler:
         with self._lock:
             self.n_cuts += 1
             self.n_cut_txns += len(cut)
-            for i, txn in outcome.committed:
+            # in cut order: _inflight keeps execution order for the release
+            for i, txn in sorted(outcome.committed, key=lambda c: c[0]):
                 t = cut[i]
                 t.txn = txn
                 t.ssn = self._ssn_of(txn)
@@ -359,11 +378,14 @@ class GroupCommitScheduler:
 
     def _release_acks(self, now: float) -> int:
         """Release every in-flight transaction whose backend drain marked it
-        durably committed, in SSN order (within one release round a RAW
-        dependency always acks before its dependent — SSNs order them).
-        With the tracer enabled the round is an ``ack`` span, and each
-        released ticket a row of its ticket table (``t_ack``: the span's
-        start, after every released commit was seen)."""
+        durably committed, in execution order: cut by cut, admission order
+        within a cut.  A RAW or WAW dependency ran in an earlier cut than
+        its dependent (it must be written back before the dependent can
+        read or overwrite it), so within one release round it acks first;
+        and the order does not depend on where cuts land.  With the tracer
+        enabled the round is an ``ack`` span, and each released ticket a
+        row of its ticket table (``t_ack``: the span's start, after every
+        released commit was seen)."""
         # one pass: a drain on a logger thread may commit a transaction
         # at any moment, and it must land in exactly one of the two lists
         ready: List[Ticket] = []
@@ -375,7 +397,6 @@ class GroupCommitScheduler:
         _trace = TRACER.enabled
         if _trace:
             _t0 = TRACER.begin(ST_ACK)
-        ready.sort(key=lambda t: t.ssn)
         self._inflight = rest
         for t in ready:
             t.status = ACKED
@@ -383,11 +404,14 @@ class GroupCommitScheduler:
             t.ack_seq = self._ack_seq
             self._ack_seq += 1
             self.n_acked += 1
+            if not t.spec.writes:
+                self.n_acked_read_only += 1
             if t._event is not None:
                 t._event.set()
         if _trace:
+            ssns = [t.ssn for t in ready]
             TRACER.record(
-                ST_ACK, txn_lo=ready[0].ssn, txn_hi=ready[-1].ssn,
+                ST_ACK, txn_lo=min(ssns), txn_hi=max(ssns),
                 t0=_t0, t1=time.perf_counter(), n_txn=len(ready),
             )
             self._record_tickets(ready, _t0)
@@ -535,6 +559,10 @@ class GroupCommitScheduler:
                 "last_exec_error": self.last_exec_error,
                 "cuts": self.n_cuts,
                 "mean_cut": self.n_cut_txns / self.n_cuts if self.n_cuts else 0.0,
+                "cut_stop_conflict": self.n_cut_stop_conflict,
+                "cut_stop_full": self.n_cut_stop_full,
+                "cut_stop_drained": self.n_cut_stop_drained,
+                "acked_read_only": self.n_acked_read_only,
                 "queue_depth": len(self._queue),
                 "max_queue_depth": self._max_queue,
                 "mean_queue_depth": sum(qs) / len(qs) if qs else 0.0,

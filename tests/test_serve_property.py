@@ -1,8 +1,9 @@
 """Group-commit transparency properties for the serving tier.
 
 The scheduler's batch cutter is strict-FIFO and conflict-free (a cut is the
-longest queue prefix with no intra-prefix key overlap), which makes group
-commit *transparent* at the log-byte level.  Two properties pin that down:
+longest queue prefix in which nothing touches a key written earlier in the
+prefix), which makes group commit *transparent* at the log-byte level.  Two
+properties pin that down:
 
 P1 (equivalence vs direct batch): for a conflict-free arrival schedule, the
    serve path — arrivals trickling in over steps, arbitrary cut sizes and
@@ -12,7 +13,8 @@ P1 (equivalence vs direct batch): for a conflict-free arrival schedule, the
    pallas and scalar executors, and for the sharded engine.
 
 P2 (cut invariance): for an *arbitrary* schedule (duplicate/hot keys — the
-   cutter splits at conflicts), any two scheduler configurations (different
+   cutter splits at conflicts; Zipf-skewed reads and read-modify-writes,
+   where reads share cuts), any two scheduler configurations (different
    cut sizes, latency budgets, arrival timings) produce byte-identical logs,
    identical final state, and the same per-transaction SSNs and ack order.
 
@@ -31,7 +33,7 @@ import pytest
 
 from repro.core import EngineConfig, PoplarEngine
 from repro.db.batch import BatchOCC, ScalarBatchOCC, TxnSpec
-from repro.db.ycsb import key_of
+from repro.db.ycsb import Zipfian, key_of
 from repro.serve import (
     ACKED,
     GroupCommitScheduler,
@@ -49,7 +51,7 @@ try:
 except ImportError:  # tier-1 containers: seeded trials below still run
     HAVE_HYPOTHESIS = False
 
-READ_POOL = 20  # keys 0..19: preloaded, read-only (never written by specs)
+READ_POOL = 20  # keys 0..19: preloaded; only the read/RMW schedules write them
 
 
 def _mk_backend(mode, base_dir, tag, n_workers):
@@ -233,6 +235,22 @@ def _arbitrary_specs(rng, n):
     return specs
 
 
+def _zipf_read_rmw_specs(rng, n):
+    """YCSB-F-like schedule: half read-only, half read-modify-write, keys
+    Zipf-skewed over a few preloaded keys.  Reads of one key share a cut,
+    an RMW joins after reads of its key, a read after a write ends it."""
+    zipf = Zipfian(6, theta=0.99, seed=rng.randrange(1 << 30))
+    specs = []
+    for r in zipf.sample(n).tolist():
+        k = key_of(r)
+        if rng.random() < 0.5:
+            specs.append(TxnSpec(reads=[k]))
+        else:
+            specs.append(TxnSpec(reads=[k], writes=[(k, rng.randbytes(
+                rng.randrange(1, 24)))]))
+    return specs
+
+
 def _check_cut_invariance(mode, base_dir, specs, cfg_a, cfg_b, n_workers):
     keys = sorted({k for s in specs
                    for k in list(s.reads) + [w for w, _ in s.writes]})
@@ -252,11 +270,16 @@ def _check_cut_invariance(mode, base_dir, specs, cfg_a, cfg_b, n_workers):
     assert results[0] == results[1]
 
 
-@pytest.mark.parametrize("seed", range(6))
-def test_cut_invariance(seed, tmp_path):
+@pytest.mark.parametrize("seed,make_specs", [
+    pytest.param(s, _arbitrary_specs, id=str(s)) for s in range(6)
+] + [
+    pytest.param(s, _zipf_read_rmw_specs, id=f"zipf_read_rmw-{s}")
+    for s in range(6)
+])
+def test_cut_invariance(seed, make_specs, tmp_path):
     rng = random.Random(100 + seed)
     n = rng.randrange(2, 24)
-    specs = _arbitrary_specs(rng, n)
+    specs = make_specs(rng, n)
     cfg_a = ([rng.randrange(0, 3) for _ in range(n)],
              rng.choice([1, 2, 4, 64]), rng.choice([1, 2]))
     cfg_b = ([rng.randrange(0, 3) for _ in range(n)],

@@ -3,6 +3,9 @@ mode, no wall clocks — except the one threaded test of executor faults,
 which exist only in the threaded loop).
 
 Covers: batch-cut triggers (size / latency budget / head-of-line FIFO), the
+cut's conflict rule (reads share a cut; a read or write of a key written
+earlier in the cut ends it) and its stop counters, a read-only ack held
+to the CSN, the
 ack = durable ∧ committable gate under partial flush interleavings (the
 Qww/Qwr watermark rule observed end-to-end through the scheduler), the RAW
 commit-order invariant under randomized flush schedules asserted against
@@ -107,6 +110,101 @@ def test_cut_head_of_line_fifo(tmp_path):
     got = sched.backend.table.get(k1)
     val = got[0] if isinstance(got, tuple) else got.value
     assert val == b"b"
+
+
+def _loaded(tmp_path, keys, **kw):
+    be = _backend(tmp_path, **kw)
+    for k in keys:
+        be.table.insert(k, b"v0-" + k.encode())
+    return be
+
+
+def test_reads_of_one_key_share_a_cut(tmp_path):
+    """Reads conflict with nothing: two read-only tickets of one key run in
+    one cut and both ack, at the SSN of the version they read."""
+    k = key_of(1)
+    sched = GroupCommitScheduler(_loaded(tmp_path, [k]),
+                                 ServeConfig(max_batch=64))
+    r1 = sched.submit(TxnSpec(reads=[k]))
+    r2 = sched.submit(TxnSpec(reads=[k]))
+    sched.step()
+    assert sched.n_cuts == 1 and sched.n_cut_txns == 2
+    assert r1.status == ACKED and r2.status == ACKED
+    assert r1.ssn == r2.ssn
+    st = sched.stats()
+    assert st["acked_read_only"] == 2 and st["cut_stop_conflict"] == 0
+
+
+def test_rmw_after_a_read_of_its_key_joins_the_cut(tmp_path):
+    """A write after a read of its key is no RAW or WAW pair: it joins the
+    cut, both win, and the reader keeps the version it read."""
+    k = key_of(1)
+    sched = GroupCommitScheduler(_loaded(tmp_path, [k]),
+                                 ServeConfig(max_batch=64))
+    r = sched.submit(TxnSpec(reads=[k]))
+    m = sched.submit(TxnSpec(reads=[k], writes=[(k, b"m")]))
+    sched.step()
+    assert sched.n_cuts == 1 and sched.n_cut_txns == 2
+    assert r.status == ACKED and m.status == ACKED
+    assert r.ssn < m.ssn
+    assert r.ack_seq < m.ack_seq
+    assert sched.backend.table.get(k)[0] == b"m"
+
+
+def test_read_after_a_write_of_its_key_ends_the_cut(tmp_path):
+    """A RAW pair never shares a cut: the reader runs in the next one and
+    reads the writer's version."""
+    k, k2 = key_of(1), key_of(2)
+    sched = GroupCommitScheduler(_loaded(tmp_path, [k, k2]),
+                                 ServeConfig(max_batch=64))
+    w = sched.submit(TxnSpec(writes=[(k, b"w")]))
+    r = sched.submit(TxnSpec(reads=[k]))
+    behind = sched.submit(TxnSpec(reads=[k2]))   # FIFO: waits behind r
+    sched.step()
+    assert sched.n_cut_txns == 1
+    assert w.status == ACKED and r.status != ACKED and behind.status != ACKED
+    sched.run_until_drained()
+    assert r.ssn == w.ssn
+    assert [w.ack_seq, r.ack_seq, behind.ack_seq] == [0, 1, 2]
+
+
+def test_stats_count_why_each_cut_ended(tmp_path):
+    sched = GroupCommitScheduler(_backend(tmp_path),
+                                 ServeConfig(max_batch=3))
+    sched.submit(_wspec(0))
+    sched.submit(_wspec(0))            # same key: the first cut stops here
+    sched.step()                       # [a]: conflict
+    sched.step()                       # [b]: queue drained
+    for i in range(1, 5):
+        sched.submit(_wspec(i))
+    sched.step()                       # three: max_batch
+    sched.step()                       # the last one: drained
+    st = sched.stats()
+    assert (st["cut_stop_conflict"], st["cut_stop_full"],
+            st["cut_stop_drained"]) == (1, 1, 2)
+    assert st["cuts"] == 4 and st["acked"] == 6
+    assert st["acked_read_only"] == 0
+
+
+def test_read_only_ack_waits_for_the_version_it_read(tmp_path):
+    """A read-only ticket reads a version whose record sits unflushed on
+    the other device: its SSN is that record's, so it is not acked until
+    the CSN (least DSN over devices) reaches it, after its writer."""
+    k = key_of(1)
+    be = _loaded(tmp_path, [k], n_workers=2, n_buffers=2, device_kind="ssd")
+    sched = GroupCommitScheduler(be, ServeConfig(max_batch=8))
+    w = sched.submit(TxnSpec(writes=[(k, b"w")]))   # worker 0 -> buffer 0
+    sched.step(tick_parts=[1])                      # buffer 0 held
+    r = sched.submit(TxnSpec(reads=[k]))            # worker 1: w's version
+    for _ in range(3):
+        sched.step(tick_parts=[1])
+    assert w.status == INFLIGHT and r.status == INFLIGHT
+    assert r.txn.buffer_id == -1 and r.ssn == w.ssn
+    assert min(b.dsn for b in be.engine.buffers) < r.ssn
+    sched.step()                                    # buffer 0 flushes
+    assert w.status == ACKED and r.status == ACKED
+    assert w.ack_seq < r.ack_seq
+    assert sched.stats()["acked_read_only"] == 1
 
 
 # --- ack gate: durable AND committable ---------------------------------------
@@ -357,6 +455,33 @@ def test_sharded_serving_with_cross_shard(tmp_path):
         assert data[k.encode()][0] == b"s-" + k.encode()
     assert data[shard0[5].encode()][0] == b"x0"
     assert data[shard1[5].encode()][0] == b"x1"
+
+
+def test_sharded_cross_shard_reader_ends_the_cut_for_a_writer(tmp_path):
+    """A cross-shard ticket's prepare runs after the cut's single-shard
+    sub-batches, so a later single-shard writer of a key it reads must wait
+    for the next cut; a single-shard writer after a single-shard reader
+    joins."""
+    be = ShardedBackend.make(n_shards=2, n_buffers=1, n_workers=2,
+                             device_kind="null", device_dir=str(tmp_path))
+    keys = [key_of(i) for i in range(40)]
+    a0, b0 = [k for k in keys if be.eng.shard_of(k) == 0][:2]
+    a1 = [k for k in keys if be.eng.shard_of(k) == 1][0]
+    for k in (a0, b0, a1):
+        be.eng.insert(k, b"v0")
+    sched = GroupCommitScheduler(be, ServeConfig(max_batch=8))
+    x = sched.submit(TxnSpec(reads=[a0, a1], writes=[(b0, b"x")]))
+    w = sched.submit(TxnSpec(writes=[(a0, b"w")]))
+    sched.step()
+    assert sched.n_cut_txns == 1 and sched.stats()["cut_stop_conflict"] == 1
+    sched.run_until_drained()
+    assert x.status == ACKED and w.status == ACKED
+    assert x.ssn < w.ssn and x.ack_seq < w.ack_seq
+    r = sched.submit(TxnSpec(reads=[a0]))
+    m = sched.submit(TxnSpec(reads=[a0], writes=[(a0, b"m")]))
+    sched.step()
+    assert sched.n_cuts == 3 and sched.n_cut_txns == 4
+    assert r.status == ACKED and m.status == ACKED
 
 
 # --- executor faults (threaded loop) -----------------------------------------

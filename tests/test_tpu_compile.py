@@ -19,7 +19,8 @@ from repro.kernels.bucketing import bucket
 V5E_HBM_BYTES = 16 * 10**9
 
 # name -> (jitted entry point, argument shapes, static kwargs, is a Pallas
-# kernel).  Shapes: served cuts are <= 256 conflict-free single-write txns;
+# kernel).  Shapes: served cuts are <= 256 conflict-free txns of one
+# write lane, or of a read and a write lane (YCSB-F's read-modify-writes);
 # bulk rounds are 8,192 txns of one access against a 10M-row table; a
 # recovery tile holds ~1-2K write lanes (1.25 MiB sealed segments).
 CASES = {
@@ -31,6 +32,9 @@ CASES = {
                            dict(n_slots=256, op="min", interpret=False), True),
     "seg_reduce_max_256": (ops.occ_seg_reduce, [(256,), (256,)],
                            dict(n_slots=256, op="max", interpret=False), True),
+    "seg_reduce_max_512_of_256": (ops.occ_seg_reduce, [(512,), (512,)],
+                                  dict(n_slots=256, op="max",
+                                       interpret=False), True),
     "ssn_scatter_max": (ops.ssn_scatter_max,
                         [(4096,), (4096,), (2048,), (2048,), (2048,)],
                         dict(interpret=False), True),
