@@ -29,6 +29,25 @@ from .storage import StorageDevice, make_devices
 from .txn import Txn
 
 
+def _drain_to(q: CommitQueues, wm: int) -> int:
+    """Commit every transaction of ``q`` with ``ssn <= wm``, the baselines'
+    single-watermark rule: Qww and Qwr from the head, the read-only heap
+    from its least SSN.  Returns the number committed."""
+    n = 0
+    with q.lock:
+        for queue in (q.qww, q.qwr):
+            while queue and queue[0].ssn <= wm:
+                txn = queue.popleft()
+                txn.t_commit = time.perf_counter()
+                txn.committed = True
+                n += 1
+        for txn in q.pop_read_only(wm):
+            txn.t_commit = time.perf_counter()
+            txn.committed = True
+            n += 1
+    return n
+
+
 class CentrEngine(PoplarEngine):
     """Centralized ARIES-style logging.
 
@@ -63,20 +82,7 @@ class CentrEngine(PoplarEngine):
     def drain(self, worker_id: int) -> int:
         # Total-order commit: everything (incl. read-only) waits on the
         # single buffer's DSN.
-        q = self.queues[worker_id]
-        n = 0
-        with q.lock:
-            dsn = self.buffers[0].dsn
-            for queue in (q.qww, q.qwr):
-                while queue:
-                    txn = queue[0]
-                    if txn.ssn <= dsn:
-                        queue.popleft()
-                        txn.committed = True
-                        txn.t_commit = time.perf_counter()
-                        n += 1
-                    else:
-                        break
+        n = _drain_to(self.queues[worker_id], self.buffers[0].dsn)
         if n:
             with self._count_lock:
                 self.txn_committed += n
@@ -169,19 +175,7 @@ class SiloEngine(LoggingEngine):
         buf = self.buffer_for(worker_id)
         if self.devices[buf.id].spec.latency_s < 5e-6:
             self.logger_tick(buf.id)  # NVM inline flush (see PoplarEngine.drain)
-        pe = self.persistent_epoch()
-        n = 0
-        with q.lock:
-            for queue in (q.qww, q.qwr):
-                while queue:
-                    txn = queue[0]
-                    if txn.ssn <= pe:
-                        queue.popleft()
-                        txn.committed = True
-                        txn.t_commit = time.perf_counter()
-                        n += 1
-                    else:
-                        break
+        n = _drain_to(q, self.persistent_epoch())
         if n:
             with self._count_lock:
                 self.txn_committed += n
@@ -367,20 +361,7 @@ class NvmDEngine(LoggingEngine):
         return wm or 0
 
     def drain(self, worker_id: int) -> int:
-        q = self.queues[worker_id]
-        wm = self._durable_watermark()
-        n = 0
-        with q.lock:
-            for queue in (q.qww, q.qwr):
-                while queue:
-                    txn = queue[0]
-                    if txn.ssn <= wm:
-                        queue.popleft()
-                        txn.committed = True
-                        txn.t_commit = time.perf_counter()
-                        n += 1
-                    else:
-                        break
+        n = _drain_to(self.queues[worker_id], self._durable_watermark())
         if n:
             with self._count_lock:
                 self.txn_committed += n

@@ -39,6 +39,16 @@ from ..shard.coordinator import XTxn
 from ..shard.engine import ShardedConfig, ShardedEngine
 
 
+def _ro_commit_counts(queues) -> Tuple[int, int]:
+    """Read-only commits over ``queues`` (commit queues), and those made
+    while an older Qwr entry of the same worker was still blocked."""
+    ro = passed = 0
+    for q in queues:
+        ro += q.ro_commits
+        passed += q.ro_commits_passed
+    return ro, passed
+
+
 class ExecOutcome:
     """Normalized result of one batch execution.
 
@@ -151,6 +161,11 @@ class SingleBackend:
         — the backend-side component of queue depth reporting."""
         return [q.pending() for q in self.engine.queues.values()]
 
+    def ro_commit_counts(self) -> Tuple[int, int]:
+        """Read-only commits, and those that passed a blocked older Qwr
+        entry of their worker, over every commit queue."""
+        return _ro_commit_counts(self.engine.queues.values())
+
     def saturated(self) -> bool:
         """Log-device saturation signal: any buffer holds more unflushed
         bytes than one io_unit — the flush pipe is behind the offered load."""
@@ -236,6 +251,13 @@ class ShardedBackend:
         ]
         out[0] += self.eng.coordinator.pending_count()
         return out
+
+    def ro_commit_counts(self) -> Tuple[int, int]:
+        """Read-only commits, and those that passed a blocked older Qwr
+        entry of their worker, over every shard's commit queues (a
+        cross-shard transaction commits in the coordinator's sweep)."""
+        return _ro_commit_counts(
+            q for sh in self.eng.shards for q in sh.engine.queues.values())
 
     def saturated(self) -> bool:
         return any(

@@ -546,6 +546,7 @@ class GroupCommitScheduler:
 
     # --- stats --------------------------------------------------------------
     def stats(self) -> Dict:
+        ro_commits, ro_passed = self.backend.ro_commit_counts()
         with self._lock:
             qs = self.queue_samples
             return {
@@ -563,6 +564,8 @@ class GroupCommitScheduler:
                 "cut_stop_full": self.n_cut_stop_full,
                 "cut_stop_drained": self.n_cut_stop_drained,
                 "acked_read_only": self.n_acked_read_only,
+                "ro_commits": ro_commits,
+                "ro_commits_passed": ro_passed,
                 "queue_depth": len(self._queue),
                 "max_queue_depth": self._max_queue,
                 "mean_queue_depth": sum(qs) / len(qs) if qs else 0.0,

@@ -151,3 +151,59 @@ def test_centr_total_order():
         w.run(t, [], [Cell()])
         ssns.append(t.ssn)
     assert ssns == sorted(ssns) and len(set(ssns)) == 5  # strict total order
+
+
+class _Cell:
+    def __init__(self):
+        self.ssn = 0
+
+
+def _centr():
+    eng = CentrEngine(EngineConfig(device_kind="null", flush_interval=60.0))
+    return eng, (lambda: None), (lambda: eng.logger_tick(0, force=True))
+
+
+def _silo():
+    eng = SiloEngine(EngineConfig(n_buffers=2, device_kind="null"),
+                     epoch_interval=3600)
+
+    def release():
+        eng.advance_epoch()
+        for i in range(2):
+            eng.logger_tick(i, force=True)
+    return eng, (lambda: None), release
+
+
+def _nvmd():
+    eng = NvmDEngine(n_workers=2, n_devices=2, device_kind="null")
+    blocker = Txn(tid=99, write_set=[("z", b"z")])
+    blocker.worker_id = 1
+
+    def hold():
+        eng.allocate(blocker, [], [_Cell()])   # in flight on device 1
+    return eng, hold, (lambda: eng.publish(blocker))
+
+
+@pytest.mark.parametrize("make", [_centr, _silo, _nvmd],
+                         ids=["centr", "silo", "nvmd"])
+def test_baselines_commit_read_only_txns_under_their_own_rule(make):
+    """A baseline's read-only txn waits on that engine's one watermark
+    (CENTR: the single buffer's DSN; SILO: the persistent epoch; NVM-D: the
+    durable GSN), is counted as pending meanwhile, and commits once the
+    watermark passes it, no earlier than the writer whose version it read."""
+    eng, hold, release = make()
+    w0, w1 = Worker(eng, 0), Worker(eng, 1)
+    a = _Cell()
+    writer = Txn(tid=1, write_set=[("a", b"1")])
+    w0.run(writer, [], [a])
+    hold()
+    ro = Txn(tid=2, read_set=[("a", a.ssn)])
+    w1.run(ro, [a], [])
+    assert ro.ssn >= writer.ssn
+    assert eng.drain(0) == 0 and eng.drain(1) == 0
+    assert eng.queues[1].pending() == 1 and not ro.committed
+    release()
+    eng.quiesce([0, 1], timeout=10)
+    assert writer.committed and ro.committed
+    assert writer.t_commit <= ro.t_commit
+    assert eng.queues[0].pending() == eng.queues[1].pending() == 0

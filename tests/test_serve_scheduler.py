@@ -5,7 +5,7 @@ which exist only in the threaded loop).
 Covers: batch-cut triggers (size / latency budget / head-of-line FIFO), the
 cut's conflict rule (reads share a cut; a read or write of a key written
 earlier in the cut ends it) and its stop counters, a read-only ack held
-to the CSN, the
+to the CSN and not behind an older blocked RMW of its worker, the
 ack = durable ∧ committable gate under partial flush interleavings (the
 Qww/Qwr watermark rule observed end-to-end through the scheduler), the RAW
 commit-order invariant under randomized flush schedules asserted against
@@ -205,6 +205,30 @@ def test_read_only_ack_waits_for_the_version_it_read(tmp_path):
     assert w.status == ACKED and r.status == ACKED
     assert w.ack_seq < r.ack_seq
     assert sched.stats()["acked_read_only"] == 1
+
+
+def test_read_only_ack_passes_a_blocked_rmw_of_its_worker(tmp_path):
+    """An RMW on worker 0 waits for its held buffer; a later read-only
+    ticket of a durable key on the same worker acks in the step that ran
+    it, not behind the RMW, and is counted as having passed it."""
+    k, d = key_of(1), key_of(2)
+    be = _loaded(tmp_path, [k, d], n_workers=2, n_buffers=2,
+                 device_kind="ssd")
+    sched = GroupCommitScheduler(be, ServeConfig(max_batch=8))
+    m = sched.submit(TxnSpec(reads=[k], writes=[(k, b"m")]))  # worker 0
+    sched.step(tick_parts=[1])                      # buffer 0 held
+    assert m.status == INFLIGHT and m.txn.buffer_id == 0
+    other = sched.submit(TxnSpec(reads=[d]))        # worker 1
+    r = sched.submit(TxnSpec(reads=[d]))            # worker 0, behind m
+    sched.step(tick_parts=[1])
+    assert r.txn.buffer_id == -1 and r.ssn == 0
+    assert r.status == ACKED and other.status == ACKED
+    assert m.status == INFLIGHT
+    st = sched.stats()
+    assert (st["ro_commits"], st["ro_commits_passed"]) == (2, 1)
+    sched.step()                                    # buffer 0 flushes
+    assert m.status == ACKED and r.ack_seq < m.ack_seq
+    assert sched.stats()["ro_commits_passed"] == 1
 
 
 # --- ack gate: durable AND committable ---------------------------------------
