@@ -1,23 +1,12 @@
-"""Roofline tables: the model dry-run renderer plus the OLTP log-pipeline
-roofline (BENCH_roofline_oltp.json).
+"""OLTP log-pipeline roofline (BENCH_roofline_oltp.json).
 
-Part 1 renders the dry-run JSONs from ``benchmarks/results/`` into
-per-(arch x shape x mesh) terms:
-
-    compute   = dot-FLOPs/device   / 197 TFLOP/s  (bf16, TPU v5e)
-    memory    = HBM bytes/device   / 819 GB/s
-    collective= ICI bytes/device   / 50 GB/s (single-link, conservative)
-
-``fraction`` = compute_s / step_lower_bound — how close the cell is to being
-compute-bound (1.0 == at the compute roofline given perfect overlap).
-
-Part 2 measures the logging/recovery pipeline the same way: each OLTP stage
-is a byte stream (log bytes in, table state out), so its roof is the
-machine's *measured* stream-copy memory bandwidth (probed at startup — the
-shared container's attainable rate, not a spec sheet), with the emulated
-SSD read bandwidth (``REPRO_SSD_BW`` x device parallelism) shown alongside
-as the IO roof the paper's recovery model divides by.  Per (stage, mode)
-row: achieved bytes/s over the stage's wall time vs those roofs, for
+Each stage of the logging/recovery pipeline is a byte stream (log bytes
+in, table state out), so its roof is the machine's *measured* stream-copy
+memory bandwidth (probed at startup — the shared container's attainable
+rate, not a spec sheet), with the emulated SSD read bandwidth
+(``REPRO_SSD_BW`` x device parallelism) shown alongside as the IO roof the
+paper's recovery model divides by.  Per (stage, mode) row: achieved
+bytes/s over the stage's wall time vs those roofs, for
 
 * ``replay`` — end-to-end ``recover()`` on segmented devices;
 * ``replica_apply`` — ship + continuous apply into a live ``ArrayTable``;
@@ -31,61 +20,16 @@ of the machine's copy bandwidth the mode sustains.
 
 from __future__ import annotations
 
-import json
 import os
 import sys
 import time
-from typing import Dict, List, Optional
 
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-RESULTS = os.path.join(os.path.dirname(__file__), "results")
 OLTP_MODES = ("scalar", "vectorized", "pallas")
 
-
-def load_results(tag: Optional[str] = None) -> List[Dict]:
-    out = []
-    if not os.path.isdir(RESULTS):
-        return out
-    for f in sorted(os.listdir(RESULTS)):
-        if not f.endswith(".json"):
-            continue
-        if tag and not f.endswith(f"__{tag}.json"):
-            continue
-        with open(os.path.join(RESULTS, f)) as fh:
-            out.append(json.load(fh))
-    return out
-
-
-def render(rows: List[Dict], title: str = "roofline") -> None:
-    hdr = (f"{'arch':24s} {'shape':12s} {'mesh':6s} {'tag':10s} "
-           f"{'compute_s':>10s} {'memory_s':>10s} {'collect_s':>10s} "
-           f"{'bound':>10s} {'step>=s':>9s} {'frac':>6s} {'peakGB':>7s} {'MF/HLO':>7s}")
-    print(f"== {title} ==")
-    print(hdr)
-    for r in rows:
-        if r.get("status") == "skipped":
-            print(f"{r['arch']:24s} {r['shape']:12s} {r['mesh']:6s} {r.get('tag','-'):10s} "
-                  f"{'SKIP':>10s}  ({r['reason'][:70]})")
-            continue
-        if r.get("status") != "ok":
-            print(f"{r['arch']:24s} {r['shape']:12s} {r['mesh']:6s} {r.get('tag','-'):10s} "
-                  f"{'FAIL':>10s}  ({r.get('error','?')[:70]})")
-            continue
-        rf = r["roofline"]
-        frac = rf["compute_s"] / rf["step_s_lower_bound"] if rf["step_s_lower_bound"] else 0
-        mem = rf.get("memory_tpu_s", rf["memory_s"])
-        print(
-            f"{r['arch']:24s} {r['shape']:12s} {r['mesh']:6s} {r.get('tag','-'):10s} "
-            f"{rf['compute_s']:10.4f} {mem:10.4f} {rf['collective_s']:10.4f} "
-            f"{rf['bottleneck']:>10s} {rf['step_s_lower_bound']:9.4f} {frac:6.3f} "
-            f"{r['memory']['peak_gb']:7.2f} {r.get('useful_flop_ratio') or 0:7.3f}"
-        )
-
-
-# --- Part 2: OLTP log-pipeline roofline ---------------------------------------
 
 def _mem_bw_probe(nbytes: int = 32 << 20, reps: int = 5) -> float:
     """Measured stream-copy bandwidth (read + write streams counted), the
@@ -221,31 +165,7 @@ def run_oltp():
 
 
 def run(duration=None):
-    rows = load_results()
-    render(rows)
-    # CSV summary for run.py
-    out = []
-    for r in rows:
-        if r.get("status") != "ok":
-            continue
-        rf = r["roofline"]
-        out.append({
-            "bench": "roofline", "arch": r["arch"], "shape": r["shape"],
-            "mesh": r["mesh"], "tag": r.get("tag", "baseline"),
-            "bottleneck": rf["bottleneck"],
-            "step_lower_bound_s": round(rf["step_s_lower_bound"], 5),
-            "compute_fraction": round(rf["compute_s"] / rf["step_s_lower_bound"], 4)
-            if rf["step_s_lower_bound"] else 0,
-            "peak_gb": r["memory"]["peak_gb"],
-        })
-    if out:
-        from _util import emit
-
-        emit(out, ["bench", "arch", "shape", "mesh", "tag", "bottleneck",
-                   "step_lower_bound_s", "compute_fraction", "peak_gb"],
-             name="roofline")
-    out.extend(run_oltp())
-    return out
+    return run_oltp()
 
 
 if __name__ == "__main__":

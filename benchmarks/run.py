@@ -1,9 +1,9 @@
 """Benchmark driver — one function per paper table/figure.
 
-Prints CSV blocks per benchmark (name,metrics...) plus the roofline table
-derived from the dry-run artifacts, and persists each benchmark's rows to
-``BENCH_<name>.json`` at the repo root (machine-readable perf trajectory
-across PRs).  BENCH_FAST=1 shrinks durations for CI.
+Prints CSV blocks per benchmark (name,metrics...) and persists each
+benchmark's rows to ``BENCH_<name>.json`` at the repo root
+(machine-readable perf trajectory across PRs).  BENCH_FAST=1 shrinks
+durations for CI.
 
 Usage: ``python benchmarks/run.py [--list] [--seed N] [bench_name ...]``
 

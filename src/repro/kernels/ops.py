@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,11 +29,8 @@ import jax.numpy as jnp
 from .batch_occ import seg_reduce as _seg_reduce_raw
 from .batch_occ import validate_sequence_xla as _validate_sequence_xla
 from .bucketing import jit_cache_size
-from .flash_attention import flash_attention_fwd
-from .rwkv6 import rwkv6_chunked
 from .scatter_max import ssn_scatter_max as _ssn_scatter_max_raw
 from .scatter_max import ssn_scatter_max_xla as _ssn_scatter_max_xla
-from .ssm_scan import ssm_scan_chunked
 
 
 _CHECKOUT = os.path.abspath(
@@ -74,33 +71,6 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
     return _default_interpret()
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
-                                             "block_q", "block_k", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
-                    softcap: Optional[float] = None, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
-    """q (B,Hq,S,D); k/v (B,Hkv,T,D) -> (B,Hq,S,D)."""
-    return flash_attention_fwd(
-        q, k, v, causal=causal, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k, interpret=_auto_interpret(interpret),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssm_scan(x, dt, decay, bmat, cmat, *, chunk: int = 64,
-             interpret: Optional[bool] = None):
-    """Chunked selective scan: returns (y, final_state)."""
-    return ssm_scan_chunked(
-        x, dt, decay, bmat, cmat, chunk=chunk, interpret=_auto_interpret(interpret)
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6(r, k, v, w, u, *, chunk: int = 32, interpret: Optional[bool] = None):
-    """Chunked wkv6: returns (y, final_state)."""
-    return rwkv6_chunked(r, k, v, w, u, chunk=chunk, interpret=_auto_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_w", "interpret"))

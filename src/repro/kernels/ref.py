@@ -1,43 +1,10 @@
-"""Pure-jnp/numpy oracles for every Pallas kernel (small-shape exact references)."""
+"""Pure-numpy oracles for every Pallas kernel (small-shape exact references)."""
 
 from __future__ import annotations
 
-import math
-from typing import Optional, Tuple
+from typing import Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-
-def attention_ref(
-    q: jax.Array,      # (B, Hq, S, D)
-    k: jax.Array,      # (B, Hkv, T, D)
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: Optional[int] = None,
-    softcap: Optional[float] = None,
-) -> jax.Array:
-    b, hq, s, d = q.shape
-    _, hkv, t, _ = k.shape
-    g = hq // hkv
-    kr = jnp.repeat(k, g, axis=1)
-    vr = jnp.repeat(v, g, axis=1)
-    scores = jnp.einsum("bhsd,bhtd->bhst", q.astype(jnp.float32), kr.astype(jnp.float32))
-    scores = scores / math.sqrt(d)
-    if softcap is not None:
-        scores = softcap * jnp.tanh(scores / softcap)
-    q_pos = jnp.arange(s)[:, None]
-    k_pos = jnp.arange(t)[None, :]
-    mask = jnp.ones((s, t), bool)
-    if causal:
-        mask &= q_pos >= k_pos
-    if window is not None:
-        mask &= q_pos - k_pos < window
-    scores = jnp.where(mask[None, None], scores, -1e30)
-    p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhst,bhtd->bhsd", p, vr.astype(jnp.float32)).astype(q.dtype)
 
 
 def scatter_max_ref(
@@ -78,46 +45,3 @@ def seg_reduce_ref(
         elif v > out[k]:
             out[k] = v
     return out.astype(np.int32)
-
-
-def ssm_scan_ref(
-    x: jax.Array,      # (B, H, S, P)   inputs per head
-    dt: jax.Array,     # (B, H, S)      softplus'd step sizes
-    decay: jax.Array,  # (B, H, S)      exp(-exp(A) dt) in (0, 1)
-    bmat: jax.Array,   # (B, S, N)
-    cmat: jax.Array,   # (B, S, N)
-    h0: Optional[jax.Array] = None,  # (B, H, P, N)
-) -> Tuple[jax.Array, jax.Array]:
-    """Naive selective-scan: h_t = a_t h + dt_t x_t ⊗ B_t ; y_t = h_t · C_t."""
-    b, h, s, p = x.shape
-    n = bmat.shape[-1]
-    hh = jnp.zeros((b, h, p, n), jnp.float32) if h0 is None else h0.astype(jnp.float32)
-    ys = []
-    for t in range(s):
-        upd = (dt[:, :, t, None] * x[:, :, t].astype(jnp.float32))[..., None] * bmat[:, None, t, None, :].astype(jnp.float32)
-        hh = decay[:, :, t, None, None] * hh + upd
-        ys.append(jnp.einsum("bhpn,bn->bhp", hh, cmat[:, t].astype(jnp.float32)))
-    y = jnp.stack(ys, axis=2)                               # (B, H, S, P)
-    return y.astype(x.dtype), hh
-
-
-def rwkv6_ref(
-    r: jax.Array,      # (B, H, S, K)
-    k: jax.Array,      # (B, H, S, K)
-    v: jax.Array,      # (B, H, S, V)
-    w: jax.Array,      # (B, H, S, K)   per-channel decay in (0, 1)
-    u: jax.Array,      # (H, K)         bonus
-    s0: Optional[jax.Array] = None,  # (B, H, K, V)
-) -> Tuple[jax.Array, jax.Array]:
-    """Naive wkv6: y_t = r_t (S_{t-1} + u ⊙ k_t^T v_t); S_t = w_t S_{t-1} + k_t^T v_t."""
-    b, h, s, kd = r.shape
-    vd = v.shape[-1]
-    S = jnp.zeros((b, h, kd, vd), jnp.float32) if s0 is None else s0.astype(jnp.float32)
-    ys = []
-    for t in range(s):
-        kv = k[:, :, t].astype(jnp.float32)[..., None] * v[:, :, t].astype(jnp.float32)[..., None, :]
-        y = jnp.einsum("bhk,bhkv->bhv", r[:, :, t].astype(jnp.float32),
-                       S + u[None, :, :, None] * kv)
-        ys.append(y)
-        S = w[:, :, t].astype(jnp.float32)[..., None] * S + kv
-    return jnp.stack(ys, axis=2).astype(v.dtype), S

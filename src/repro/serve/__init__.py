@@ -10,9 +10,6 @@
   ShardedEngine).
 * :class:`~repro.serve.driver.OpenLoopDriver` — Poisson open-loop client
   sessions with coordinated-omission-safe latency accounting.
-
-(The LLM token-serving engine formerly here lives in
-``repro.models.serve_llm``.)
 """
 
 from .backend import ExecOutcome, ShardedBackend, SingleBackend

@@ -126,13 +126,9 @@ def _run_trial(seed: int, tmp_path, mode: str) -> None:
 
 
 @pytest.mark.parametrize("seed", range(8))
-def test_batched_equals_scalar_oracle(seed, tmp_path):
-    _run_trial(seed, tmp_path, mode="vectorized")
-
-
-@pytest.mark.parametrize("seed", range(2))
-def test_batched_equals_scalar_oracle_pallas(seed, tmp_path):
-    _run_trial(seed, tmp_path, mode="pallas")
+@pytest.mark.parametrize("mode", ["vectorized", "pallas"])
+def test_batched_equals_scalar_oracle(mode, seed, tmp_path):
+    _run_trial(seed, tmp_path, mode=mode)
 
 
 def test_ycsb_write_only_batch(tmp_path):

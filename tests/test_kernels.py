@@ -1,111 +1,16 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles.
+"""Per-kernel shape sweeps of the OLTP kernels: Pallas (interpret=True) and
+the compiled fused entry points vs the ref.py oracles, exact in int32."""
 
-bf16 tolerances: inputs are cast to bf16 (~3 decimal digits), accumulation
-is fp32 in both kernel and oracle, so output atol is dominated by the input
-rounding — 2e-2 absolute on O(1) data.
-"""
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention_fwd
-from repro.kernels.ops import (flash_attention, occ_seg_reduce, rwkv6,
-                               ssm_scan, ssn_scatter_max)
-from repro.kernels.ref import (attention_ref, rwkv6_ref, scatter_max_ref,
-                               seg_reduce_ref, ssm_scan_ref)
+from repro.kernels.batch_occ import NO_WRITER
+from repro.kernels.bucketing import bucket, pad_i32, stack_i32
+from repro.kernels.ops import (fused_replay_apply, fused_replay_scan,
+                               fused_validate_sequence, occ_seg_reduce,
+                               ssn_scatter_max)
+from repro.kernels.ref import scatter_max_ref, seg_reduce_ref
 from repro.kernels.scatter_max import NO_POS
-
-RNG = np.random.default_rng(42)
-
-
-def _tol(dtype):
-    return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 else dict(atol=2e-4, rtol=2e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize(
-    "b,hq,hkv,s,t,d,causal,window,softcap",
-    [
-        (1, 2, 2, 128, 128, 128, True, None, None),
-        (2, 4, 2, 256, 256, 128, True, None, None),    # GQA
-        (1, 2, 1, 128, 256, 128, False, None, None),   # bidir, longer kv
-        (2, 2, 2, 256, 256, 128, True, 64, None),      # sliding window
-        (1, 2, 2, 128, 128, 128, True, None, 30.0),    # grok softcap
-        (1, 8, 2, 384, 384, 128, True, 128, None),     # window + GQA
-    ],
-)
-def test_flash_attention_vs_ref(b, hq, hkv, s, t, d, causal, window, softcap, dtype):
-    q = jnp.asarray(RNG.normal(0, 1, (b, hq, s, d)), dtype)
-    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, t, d)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, t, d)), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window, softcap=softcap,
-                          interpret=True)
-    ref = attention_ref(q, k, v, causal=causal, window=window, softcap=softcap)
-    np.testing.assert_allclose(
-        out.astype(jnp.float32), ref.astype(jnp.float32), **_tol(dtype)
-    )
-
-
-def test_flash_attention_rejects_misaligned():
-    q = jnp.zeros((1, 2, 100, 128))  # 100 not a multiple of block_q
-    k = v = jnp.zeros((1, 2, 128, 128))
-    with pytest.raises(AssertionError):
-        flash_attention_fwd(q, k, v, interpret=True)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize(
-    "b,h,s,p,n,chunk",
-    [(1, 2, 128, 16, 8, 64), (2, 3, 64, 32, 16, 32), (1, 1, 256, 8, 4, 64)],
-)
-def test_ssm_scan_vs_ref(b, h, s, p, n, chunk, dtype):
-    x = jnp.asarray(RNG.normal(0, 1, (b, h, s, p)), dtype)
-    dt = jnp.asarray(RNG.uniform(0.01, 0.2, (b, h, s)), jnp.float32)
-    decay = jnp.asarray(RNG.uniform(0.7, 0.999, (b, h, s)), jnp.float32)
-    bm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), dtype)
-    cm = jnp.asarray(RNG.normal(0, 1, (b, s, n)), dtype)
-    y, st = ssm_scan(x, dt, decay, bm, cm, chunk=chunk, interpret=True)
-    yr, str_ = ssm_scan_ref(x, dt, decay, bm, cm)
-    np.testing.assert_allclose(
-        y.astype(jnp.float32), yr.astype(jnp.float32), **_tol(dtype)
-    )
-    np.testing.assert_allclose(st, str_, atol=5e-2 if dtype == jnp.bfloat16 else 2e-4,
-                               rtol=5e-2 if dtype == jnp.bfloat16 else 2e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize(
-    "b,h,s,kd,vd,chunk",
-    [(1, 2, 64, 16, 16, 32), (2, 2, 128, 32, 32, 32), (1, 1, 96, 64, 64, 32)],
-)
-def test_rwkv6_vs_ref(b, h, s, kd, vd, chunk, dtype):
-    r = jnp.asarray(RNG.normal(0, 0.5, (b, h, s, kd)), dtype)
-    k = jnp.asarray(RNG.normal(0, 0.5, (b, h, s, kd)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, h, s, vd)), dtype)
-    w = jnp.asarray(RNG.uniform(0.5, 0.999, (b, h, s, kd)), jnp.float32)
-    u = jnp.asarray(RNG.normal(0, 0.5, (h, kd)), jnp.float32)
-    y, st = rwkv6(r, k, v, w, u, chunk=chunk, interpret=True)
-    yr, str_ = rwkv6_ref(r, k, v, w, u)
-    np.testing.assert_allclose(
-        y.astype(jnp.float32), yr.astype(jnp.float32), **_tol(dtype)
-    )
-
-
-def test_rwkv6_strong_decay_stability():
-    """Strong decay (w -> 0) must not overflow: the kernel uses only
-    later-minus-earlier log-cumsum differences (exponents <= 0)."""
-    b, h, s, kd, vd = 1, 1, 64, 16, 16
-    r = jnp.asarray(RNG.normal(0, 0.5, (b, h, s, kd)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 0.5, (b, h, s, kd)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (b, h, s, vd)), jnp.float32)
-    w = jnp.full((b, h, s, kd), 0.01, jnp.float32)  # extreme decay
-    u = jnp.zeros((h, kd), jnp.float32)
-    y, st = rwkv6(r, k, v, w, u, chunk=32, interpret=True)
-    yr, _ = rwkv6_ref(r, k, v, w, u)
-    assert bool(jnp.isfinite(y).all())
-    np.testing.assert_allclose(y, yr, atol=1e-4, rtol=1e-3)
 
 
 @pytest.mark.parametrize(
@@ -186,47 +91,6 @@ def test_occ_seg_reduce_empty_items():
     )
 
 
-# --- model-level optimized-impl equivalence (flash vjp, chunked mixers) ------
-
-def test_flash_vjp_matches_masked_scan():
-    from repro.models.attention import attend
-
-    b, s, h, kv, d = 2, 64, 4, 2, 16
-    q = jnp.asarray(RNG.normal(0, 1, (b, s, h, d)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (b, s, kv, d)), jnp.float32)
-
-    def loss(impl):
-        def f(q, k, v):
-            o = attend(q, k, v, causal=True, impl=impl, chunk_k=32)
-            return (o.astype(jnp.float32) ** 2).sum()
-        return f
-
-    l0, g0 = jax.value_and_grad(loss("masked_scan"), argnums=(0, 1, 2))(q, k, v)
-    l1, g1 = jax.value_and_grad(loss("flash"), argnums=(0, 1, 2))(q, k, v)
-    np.testing.assert_allclose(l0, l1, rtol=2e-4)
-    for a, b_ in zip(g0, g1):
-        np.testing.assert_allclose(a, b_, atol=3e-3, rtol=3e-3)
-
-
-@pytest.mark.parametrize("arch", ["hymba-1.5b", "rwkv6-7b"])
-def test_chunked_mixer_matches_scan(arch):
-    import dataclasses
-
-    from repro.configs.base import ShapeConfig, reduced
-    from repro.configs.registry import get_config, make_inputs
-    from repro.models.api import build_model
-
-    r = reduced(get_config(arch), n_layers=2)
-    m0 = build_model(dataclasses.replace(r, mixer_impl="scan"))
-    m1 = build_model(dataclasses.replace(r, mixer_impl="chunked"))
-    params = m0.init(jax.random.PRNGKey(0))
-    batch = make_inputs(r, ShapeConfig("t", 64, 2, "train"))
-    l0 = m0.train_loss(params, batch)
-    l1 = m1.train_loss(params, batch)
-    np.testing.assert_allclose(l0, l1, rtol=2e-3)
-
-
 # --- compiled XLA twins of the OLTP kernels ----------------------------------
 
 def _xla_twin_inputs(n_slots, n_writes, ckpt_frac, seed, pad_lanes=0):
@@ -281,8 +145,6 @@ def test_scatter_max_xla_equals_pallas_and_ref(n_slots, n_writes, ckpt_frac,
 def test_fused_replay_entry_points_match_ref():
     """The jitted fused entry points (stacked single-transfer layouts used by
     the recovery/replica hot paths) == the sequential oracle."""
-    from repro.kernels.ops import fused_replay_apply, fused_replay_scan
-
     n_slots, n_writes, pad = 256, 900, 124
     image_ssn, image_pos, key, ssn, pos = _xla_twin_inputs(
         n_slots, n_writes, 0.4, seed=99, pad_lanes=pad)
@@ -360,3 +222,107 @@ def test_validate_sequence_xla_vs_brute(n_txn, k, cap, lock_frac):
     ref_sv, ref_b = _validate_brute(acc, a_len, n_txn, k)
     np.testing.assert_array_equal(np.asarray(out_sv), ref_sv)
     np.testing.assert_array_equal(np.asarray(out_b, np.int64), ref_b)
+
+
+# --- the fused entry points across the bucket ladder ---------------------------
+
+# Lane counts the served path feeds the fused entry points: below the smallest
+# rung, exactly a rung, a rung plus one, the largest rung a latency cut of
+# <= 256 single-key transactions reaches, the fused round's engagement
+# threshold (``BatchOCC.fused_min_lanes``), and a 4,096-transaction cut.
+LADDER_LANES = (3, 64, 65, 256, 2048, 4096)
+LADDER_ENTRIES = ("occ_seg_reduce_max", "occ_seg_reduce_min",
+                  "fused_validate_sequence", "fused_replay_scan",
+                  "fused_replay_apply")
+
+
+def _ladder_occ_seg_reduce(rng, lanes, op):
+    """Padded as ``BatchOCC`` pads it: items and slots each to a power of
+    two, pad items keyed ``-1`` with the reduction identity as value."""
+    n_keys = max(1, lanes // 3)
+    key = rng.integers(0, n_keys, lanes)
+    val = rng.integers(0, 1 << 20, lanes)
+    n_items = bucket(lanes, min_size=1)
+    out = occ_seg_reduce(pad_i32(key, n_items, -1),
+                         pad_i32(val, n_items, -1 if op == "max" else NO_WRITER),
+                         n_slots=bucket(n_keys, min_size=1), op=op)
+    np.testing.assert_array_equal(np.asarray(out)[:n_keys],
+                                  seg_reduce_ref(key, val, n_keys, op))
+
+
+def _ladder_validate_sequence(rng, lanes):
+    """Padded as ``BatchOCC._fused_round`` pads it: a dense ``(n_txn, k)``
+    layout with ragged access counts, transactions to a bucket, padded
+    transactions at ``a_len = 0``; compared with the brute-force walk."""
+    k = 2
+    n_active = -(-lanes // k)
+    n_txn = bucket(n_active)
+    a_len = rng.integers(1, k + 1, n_active).astype(np.int32)
+    rows = max(8, lanes // 2)                       # hot rows: real conflicts
+    dense = n_txn * k
+    acc = np.zeros((6, dense), np.int32)
+    acc[0] = rng.integers(0, rows, dense)
+    acc[1] = np.arange(dense)                       # txn-major batch position
+    acc[2] = rng.integers(0, 2, dense)
+    ssn = rng.integers(1, 1 << 20, dense).astype(np.int32)
+    acc[4] = ssn
+    obs = np.where(rng.random(dense) < 0.5, ssn, -1)
+    acc[3] = np.where((rng.random(dense) < 0.1) & (obs >= 0), obs + 1, obs)
+    acc[5] = rng.random(dense) < 0.05
+    survive, bases = fused_validate_sequence(acc, pad_i32(a_len, n_txn, 0),
+                                             n_txn=n_txn, k=k,
+                                             cap=bucket(rows))
+    ref_sv, ref_b = _validate_brute(acc, a_len, n_active, k)
+    np.testing.assert_array_equal(np.asarray(survive)[:n_active], ref_sv)
+    np.testing.assert_array_equal(np.asarray(bases, np.int64)[:n_active], ref_b)
+    assert np.asarray(survive)[n_active:].all()     # padded txns are vacuous
+
+
+def _ladder_replay(rng, lanes, preloaded):
+    """Padded as recovery pads it: lanes to a bucket with overflow-slot
+    identity lanes ``(S, -1, NO_POS)``; slots to a bucket (scan) or the
+    image's own bucket (apply, with checkpoint entries at pos -1)."""
+    n_slots = max(1, lanes // 2)
+    slot = rng.integers(0, n_slots, lanes)
+    ssn = rng.integers(0, max(4, lanes // 4), lanes)   # dense: many ties
+    pos = np.arange(lanes)
+    n_lanes = bucket(lanes)
+    if preloaded:
+        s_pad = bucket(n_slots)
+        image_ssn = np.full(n_slots, -1, np.int32)
+        image_pos = np.full(n_slots, NO_POS, np.int32)
+        ckpt = rng.random(n_slots) < 0.3
+        image_ssn[ckpt] = rng.integers(0, max(4, lanes // 4), ckpt.sum())
+        image_pos[ckpt] = -1
+        image = stack_i32([image_ssn, image_pos], s_pad, fills=(-1, int(NO_POS)))
+        scan = stack_i32([slot, ssn, pos], n_lanes,
+                         fills=(s_pad, -1, int(NO_POS)))
+        out_ssn, out_pos = fused_replay_apply(image, scan)
+    else:
+        s_pad = 2 * n_lanes                         # recovery's hash slots
+        image_ssn = np.full(n_slots, -1, np.int32)
+        image_pos = np.full(n_slots, NO_POS, np.int32)
+        scan = stack_i32([slot, ssn, pos], n_lanes,
+                         fills=(s_pad, -1, int(NO_POS)))
+        out_ssn, out_pos = fused_replay_scan(scan, n_slots=s_pad)
+    ref_ssn, ref_pos = scatter_max_ref(image_ssn, image_pos, slot, ssn, pos)
+    out_ssn, out_pos = np.asarray(out_ssn), np.asarray(out_pos)
+    np.testing.assert_array_equal(out_ssn[:n_slots], ref_ssn)
+    np.testing.assert_array_equal(out_pos[:n_slots], ref_pos)
+    # slots no real lane maps to keep the empty identity: padding won nothing
+    assert (out_ssn[n_slots:s_pad] == -1).all()
+    assert (out_pos[n_slots:s_pad] == NO_POS).all()
+
+
+@pytest.mark.parametrize("lanes", LADDER_LANES)
+@pytest.mark.parametrize("entry", LADDER_ENTRIES)
+def test_fused_entry_points_on_the_ladder(entry, lanes):
+    """Every fused entry point the chip runs, at the padded shapes the cells
+    step through, equals its sequential oracle on the real lanes."""
+    rng = np.random.default_rng(lanes * 7 + LADDER_ENTRIES.index(entry))
+    if entry.startswith("occ_seg_reduce"):
+        _ladder_occ_seg_reduce(rng, lanes, entry.rsplit("_", 1)[1])
+    elif entry == "fused_validate_sequence":
+        _ladder_validate_sequence(rng, lanes)
+    else:
+        _ladder_replay(rng, lanes, preloaded=entry == "fused_replay_apply")

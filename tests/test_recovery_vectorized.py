@@ -25,7 +25,10 @@ from repro.core import (
     recover,
     replay_columnar,
 )
+from repro.core.fastdecode import decode_fast_tile
 from repro.core.recovery import RecoveredState, _replay_scalar, compute_rsne
+from repro.core.txn import decode_columnar_stream
+from repro.db import ArrayTable, BatchOCC, TxnSpec, tpcc, ycsb
 
 KEYS = [f"k{i}" for i in range(8)] + ["k\x00nul", ""]
 
@@ -213,3 +216,73 @@ def test_columnar_roundtrip_matches_rows():
     ]
     assert cols.last_ssn == rows[-1].ssn
     assert np.array_equal(cols.wr_klen, [len(k) for k, _ in sum((r.writes for r in rows), [])])
+
+
+# --- the four decoders agree ---------------------------------------------------
+
+def _served_log(traffic: str) -> bytes:
+    """One device's log bytes after the batched executor ran a traffic."""
+    table = ArrayTable()
+    engine = PoplarEngine(EngineConfig(n_buffers=1, device_kind="null"))
+    occ = BatchOCC(table, engine, n_workers=2)
+    rng = random.Random(7)
+    if traffic == "ycsb_wo":
+        batches = [ycsb.YCSBWriteOnly(64, seed=b, theta=0.99).next_batch(16)
+                   for b in range(3)]
+    elif traffic == "ycsbf":
+        batches = []
+        for _ in range(3):
+            keys = [ycsb.key_of(rng.randrange(16)) for _ in range(16)]
+            batches.append([
+                TxnSpec(reads=[k]) if rng.random() < 0.5 else
+                TxnSpec(reads=[k], writes=[(k, rng.randbytes(100))])
+                for k in keys])
+    else:
+        tpcc.load(table, warehouses=2)
+        gen = tpcc.TPCC(None, warehouses=2, seed=3)
+        batches = [gen.next_batch(8, lookup=table.get_or_insert)
+                   for _ in range(3)]
+    for specs in batches:
+        occ.execute_batch(specs, max_rounds=2)
+        engine.logger_tick(0, force=True)
+    engine.devices[0].close()
+    return engine.devices[0].read_all()
+
+
+def _rows(records):
+    return [(r.ssn, r.tid, r.has_reads, r.writes, r.xdep, r.cmd_op, r.cmd_deps)
+            for r in records]
+
+
+@pytest.mark.parametrize("tail", ["clean", "torn", "corrupt"])
+@pytest.mark.parametrize("traffic", ["ycsb_wo", "ycsbf", "tpcc"])
+def test_decoders_agree(traffic, tail):
+    """``decode_records``, ``decode_columnar``, ``decode_columnar_stream``
+    and ``decode_fast_tile`` yield the same records from a served log, and
+    truncate a torn or corrupt tail at the same frame; the fast tile may
+    decline (``None``) instead."""
+    buf = _served_log(traffic)
+    n_all = len(decode_records(buf))
+    assert n_all > 4
+    if tail == "torn":
+        buf = buf[:-5]
+    elif tail == "corrupt":
+        mid = len(buf) // 2                 # one payload byte: crc mismatch
+        buf = buf[:mid] + bytes([buf[mid] ^ 0xFF]) + buf[mid + 1:]
+
+    rows = _rows(decode_records(buf))
+    assert (len(rows) == n_all) == (tail == "clean")
+    assert _rows(decode_columnar(buf).to_records()) == rows
+    col, consumed = decode_columnar_stream(buf)
+    assert _rows(col.to_records()) == rows
+    assert (consumed == len(buf)) == (tail == "clean")
+
+    tile = decode_fast_tile(buf)
+    if tile is None:
+        return
+    assert tile.consumed == consumed
+    np.testing.assert_array_equal(tile.ssn, [r[0] for r in rows])
+    np.testing.assert_array_equal(tile.has_reads, [r[2] for r in rows])
+    np.testing.assert_array_equal(tile.wr_rec, col.wr_rec)
+    assert tile.keys_fixed.tolist() == col.keys_fixed.tolist()
+    assert tile.values_for(np.arange(len(tile.wr_rec))) == col.values

@@ -150,19 +150,13 @@ def _conflict_free_trial(seed, base_dir, mode):
                        n_workers=rng.choice([1, 2, 3]))
 
 
+MODES = ("vectorized", "scalar", "pallas")
+
+
 @pytest.mark.parametrize("seed", range(6))
-def test_equivalence_vectorized(seed, tmp_path):
-    _conflict_free_trial(seed, tmp_path, "vectorized")
-
-
-@pytest.mark.parametrize("seed", range(3))
-def test_equivalence_scalar(seed, tmp_path):
-    _conflict_free_trial(seed, tmp_path, "scalar")
-
-
-@pytest.mark.parametrize("seed", range(2))
-def test_equivalence_pallas(seed, tmp_path):
-    _conflict_free_trial(seed, tmp_path, "pallas")
+@pytest.mark.parametrize("mode", MODES)
+def test_equivalence(mode, seed, tmp_path):
+    _conflict_free_trial(seed, tmp_path, mode)
 
 
 # --- P1, sharded --------------------------------------------------------------
@@ -284,7 +278,7 @@ def test_cut_invariance(seed, make_specs, tmp_path):
              rng.choice([1, 2, 4, 64]), rng.choice([1, 2]))
     cfg_b = ([rng.randrange(0, 3) for _ in range(n)],
              rng.choice([1, 3, 8, 64]), rng.choice([1, 3]))
-    mode = ("vectorized", "scalar")[seed % 2]
+    mode = MODES[seed % len(MODES)]
     _check_cut_invariance(mode, tmp_path, specs, cfg_a, cfg_b,
                           n_workers=rng.choice([1, 2]))
 
